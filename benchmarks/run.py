@@ -44,6 +44,9 @@ def main() -> None:
     args = ap.parse_args()
 
     _ensure_device_mesh()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from .figures import ALL
     names = args.only.split(",") if args.only else list(ALL)
     print("name,us_per_call,derived")

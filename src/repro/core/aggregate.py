@@ -8,7 +8,7 @@ identical; the executor treats it as another deferred decision point.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -113,7 +113,8 @@ def group_aggregate_linear(rel: Relation, key: str, values: Dict[str, str],
 
 def _group_reduce_impl(keys, valid, cols, fns, num_segments, use_kernel):
     """Device group-by core: factorize the key axis ON DEVICE (sort + run
-    boundaries), then segment-reduce every aggregate column.
+    boundaries), then segment-reduce every aggregate column.  ``use_kernel``
+    holds, per column, whether its sum/count takes the Pallas segment sum.
 
     ``valid`` masks physical rows that are not logical rows (the device-
     resident pipeline's capacity padding / filtered rows); masked rows carry
@@ -148,14 +149,14 @@ def _group_reduce_impl(keys, valid, cols, fns, num_segments, use_kernel):
         jnp.where(vmask, sk, jnp.iinfo(keys.dtype).min), seg,
         num_segments=num_segments)
     results = []
-    for col, fn in zip(cols, fns):
+    for col, fn, kernel in zip(cols, fns, use_kernel):
         v = jnp.take(col.astype(jnp.float64), order)
         if fn == "sum":
             r = segment_sum_dispatch(jnp.where(vmask, v, 0.0), seg,
-                                     num_segments, use_kernel)
+                                     num_segments, kernel)
         elif fn == "count":
             r = segment_sum_dispatch(vmask.astype(jnp.float64), seg,
-                                     num_segments, use_kernel)
+                                     num_segments, kernel)
         elif fn == "min":
             r = jax.ops.segment_min(jnp.where(vmask, v, jnp.inf), seg,
                                     num_segments=num_segments)
@@ -170,18 +171,20 @@ def _group_reduce_impl(keys, valid, cols, fns, num_segments, use_kernel):
 
 
 def group_aggregate_device(rel, key: str, values: Dict[str, str],
-                           use_kernel: bool = None):
+                           max_abs: Optional[Dict[str, Optional[int]]] = None):
     """Device-resident GROUP BY: DeviceRelation → DeviceRelation, zero syncs.
 
     The seed's tensor group-by factorized keys on the host (np.unique) —
     a full device→host→device round trip per operator.  Here factorization
     is a device sort; the output stays device-resident with its real group
-    count carried as a prefix validity mask.
+    count carried as a prefix validity mask.  ``max_abs`` gives, per value
+    column, the largest |value| where the caller knows it (host data); the
+    segment-sum kernel's exactness rule reads it.
     """
     import jax.numpy as jnp
 
     from .device_relation import DeviceRelation
-    from .tensor_engine import use_pallas
+    from .tensor_engine import segment_sum_uses_kernel
 
     cols_in = tuple(rel.col(c) for c in values)
     fns = tuple(values.values())
@@ -210,8 +213,15 @@ def group_aggregate_device(rel, key: str, values: Dict[str, str],
         return (DeviceRelation.from_arrays(out_cols),
                 OpMetrics(op="group_aggregate", path="tensor", rows_in=0,
                           rows_out=0, wall_s=0.0, spill=SpillAccount()))
-    if use_kernel is None:
-        use_kernel = use_pallas(n)
+    # the segment-sum kernel's written rule, per column, before tracing:
+    # counts add booleans; sums add values bounded by ``max_abs`` where the
+    # caller knows the data, else by the column's dtype
+    max_abs = max_abs or {}
+    use_kernel = tuple(
+        segment_sum_uses_kernel(n, n, bool) if agg == "count"
+        else segment_sum_uses_kernel(n, n, c.dtype, max_abs.get(name))
+        if agg == "sum" else False
+        for name, c, agg in zip(values, cols_in, fns))
     with Timer() as t:
         fn = _group_reduce_jit()
         uniq, results, valid_out = fn(keys_dev, rel.valid, cols_in, fns, n,
@@ -255,10 +265,12 @@ def group_aggregate_tensor(rel: Relation, key: str, values: Dict[str, str],
     Host-Relation API over :func:`group_aggregate_device`: lift, reduce on
     device, one batched fetch."""
     from .device_relation import DeviceRelation
+    from .tensor_engine import host_max_abs
 
     dev = DeviceRelation.from_host(rel)
+    bounds = {c: host_max_abs(np.asarray(rel[c])) for c in values}
     with Timer() as t:
-        out_dev, m = group_aggregate_device(dev, key, values)
+        out_dev, m = group_aggregate_device(dev, key, values, max_abs=bounds)
         syncs = 1
         if out_dev.valid is not None:
             # group outputs are padded to the physical row count; fetch the

@@ -28,6 +28,8 @@ import dataclasses
 import time
 from typing import Callable, List, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .device_relation import DeviceRelation
@@ -128,6 +130,16 @@ PHYSICAL_NODES = (Scan, Filter, Join, Sort, Aggregate, GroupBy, Project)
 # registry, costing at most one extra unleased run per shape — jax's own
 # compile cache grows one (much larger) entry per shape regardless.
 import threading as _threading
+
+# What a predicate that cannot run on device raises — the ONLY failures that
+# send a query from the device to the host: tracer conversions (np.nonzero,
+# Python ``if`` on a traced bool) and numpy-only attributes (``.flags``)
+# that device arrays lack.  Compile, lowering and runtime errors of the
+# device path are not in this tuple: they propagate.
+_HOST_ONLY_PREDICATE = (jax.errors.TracerArrayConversionError,
+                        jax.errors.ConcretizationTypeError,
+                        jax.errors.TracerBoolConversionError,
+                        AttributeError)
 
 _WARM_SIGS: set = set()
 _WARM_SIG_LOCK = _threading.Lock()
@@ -793,8 +805,8 @@ class Executor:
                                              sp.reason)
                 self.broker.note_switch()
                 return None
-            except Exception:
-                # e.g. a predicate that cannot trace (np.nonzero & friends):
+            except _HOST_ONLY_PREDICATE:
+                # a predicate that cannot trace (np.nonzero & friends):
                 # fall back to the generic walk, which evaluates it on host
                 decisions.pop()
                 return None
@@ -915,10 +927,9 @@ class Executor:
             child = self._exec(node.child, metrics, decisions, mgr)
             if isinstance(child, DeviceRelation):
                 try:
-                    import jax.numpy as jnp
                     mask = jnp.asarray(node.predicate(child), bool)
                     return child.mask_and(mask)
-                except Exception:
+                except _HOST_ONLY_PREDICATE:
                     # predicate needs host numpy: a real regime crossing,
                     # accounted against this operator
                     n_in = len(child)

@@ -659,7 +659,6 @@ def _build_sharded_program(spec: FusedSpec, key: str, num_parts: int,
     full dictionary, and a data refresh never recompiles.  The join key
     stays logical int64 (the sentinel-padding contract).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PSpec
 
     from ..distributed.sharding import PART_AXIS, relational_mesh
@@ -712,11 +711,11 @@ def _build_sharded_program(spec: FusedSpec, key: str, num_parts: int,
                 "scalar": scalar,
                 "agg_n": jax.lax.psum(valid.sum(), PART_AXIS)}
 
-    mapped = shard_map(shard_body, mesh=mesh,
-                       in_specs=(PSpec(PART_AXIS), PSpec(PART_AXIS),
-                                 PSpec(), PSpec(), PSpec(), PSpec(),
-                                 PSpec(PART_AXIS), PSpec(PART_AXIS)),
-                       out_specs=PSpec())
+    mapped = jax.shard_map(shard_body, mesh=mesh,
+                           in_specs=(PSpec(PART_AXIS), PSpec(PART_AXIS),
+                                     PSpec(), PSpec(), PSpec(), PSpec(),
+                                     PSpec(PART_AXIS), PSpec(PART_AXIS)),
+                           out_specs=PSpec())
     return jax.jit(mapped)
 
 

@@ -352,7 +352,13 @@ class PathSelector:
         is the GANG wait — the max over the quote's per-lane expected waits,
         because a gang dispatch blocks on its slowest lane."""
         if self.force:
-            return Decision(self.force, "forced", 0.0, 0.0, 0)
+            # a forced device path still honors the caller's shard opt-in:
+            # an eligible fragment fans out over up to max_shards devices
+            shards = (self._sharded_candidate(spec, build, probe,
+                                              max_shards)[0]
+                      if self.force == "tensor" else 1)
+            return Decision(self.force, "forced", 0.0, 0.0, 0,
+                            shards=shards)
         import math
 
         from .tensor_engine import capacity_bucket

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import math
 import os
+from collections import Counter
 from functools import partial
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -66,7 +67,9 @@ __all__ = [
     "sort_perm_device",
     "use_pallas",
     "segment_sum_dispatch",
+    "segment_sum_uses_kernel",
     "radix_hash_probe_dispatch",
+    "kernels_traced",
 ]
 
 # Distinct sentinels so masked-out build rows can never meet masked-out probe
@@ -92,17 +95,18 @@ def capacity_bucket(n: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel dispatch (interpret-mode fallback on CPU)
+# Pallas kernel dispatch
 # ---------------------------------------------------------------------------
 
 def use_pallas(num_segments: Optional[int] = None) -> bool:
-    """Should the engine route segment/sort inner loops to Pallas kernels?
+    """Should the engine route segment/probe inner loops to Pallas kernels?
 
     ``REPRO_PALLAS=1`` forces the kernels on (interpret mode off-TPU),
     ``REPRO_PALLAS=0`` forces pure jnp, and the default ``auto`` uses the
     kernels on TPU backends only — interpret mode is a correctness fallback,
-    not a fast path.  The one-hot segment-sum kernel is additionally gated to
-    modest segment counts (its accumulator tile is [tblk, num_segments]).
+    not a fast path.  The one-hot kernels are additionally gated to
+    modest segment counts / code domains (their VMEM tiles are
+    [tile, num_segments]).
     """
     env = os.environ.get("REPRO_PALLAS", "auto")
     if env == "0":
@@ -114,17 +118,67 @@ def use_pallas(num_segments: Optional[int] = None) -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _interpret() -> bool:
+    """Interpret mode only off the chip: a TPU backend always runs the
+    compiled kernel, whatever forced the dispatch."""
+    return jax.default_backend() != "tpu"
+
+
+#: every integer of magnitude below 2**24 is exact in float32
+_F32_EXACT = 1 << 24
+
+# one name per program a Pallas kernel is traced into (list.append is atomic,
+# so concurrent serving threads tracing programs lose no entry)
+_KERNELS_TRACED: list = []
+
+
+def kernels_traced() -> Dict[str, int]:
+    """How many programs each Pallas kernel has been traced into so far in
+    this process (a kernel that never appears here never ran)."""
+    return dict(Counter(_KERNELS_TRACED))
+
+
+def segment_sum_uses_kernel(num_segments: int, n_rows: int, dtype,
+                            max_abs: Optional[int] = None) -> bool:
+    """The dispatch rule of the segment-sum kernel, decided before tracing.
+
+    The kernel adds in float32, so it takes a sum only where the float32
+    result is provably exact — the same number the jnp core gives:
+
+      * :func:`use_pallas` enables kernels for ``num_segments``;
+      * the values are integers or booleans (float columns never take it);
+      * ``n_rows * bound < 2**24``, where ``bound`` is ``max_abs`` when the
+        caller knows the data's largest |value|, else the dtype's range —
+        then every partial sum is an integer below 2**24.
+    """
+    if not use_pallas(num_segments):
+        return False
+    dt = np.dtype(dtype)
+    if dt == np.bool_:
+        bound = 1
+    elif dt.kind in "iu":
+        info = np.iinfo(dt)
+        bound = max(abs(int(info.min)), int(info.max))
+        if max_abs is not None:
+            bound = min(bound, int(max_abs))
+    else:
+        return False
+    return n_rows * bound < _F32_EXACT
+
+
 def segment_sum_dispatch(values: jnp.ndarray, seg_ids: jnp.ndarray,
                          num_segments: int, use_kernel: bool) -> jnp.ndarray:
     """Segment sum via the Pallas kernel when requested, else pure jnp.
 
-    ``use_kernel`` is resolved by the caller *outside* any jit trace (via
-    :func:`use_pallas`) so the env-var toggle is honored per call, not frozen
-    into a compiled program.
+    ``use_kernel`` comes from :func:`segment_sum_uses_kernel`, resolved by
+    the caller *outside* any jit trace so the rule and the env-var toggle
+    are honored per call, not frozen into a compiled program.
     """
     if use_kernel:
         from ..kernels.segment_join.ops import segment_sum as _pallas_segsum
-        return _pallas_segsum(seg_ids, values, num_segments).astype(values.dtype)
+        _KERNELS_TRACED.append("segment_sum")
+        return _pallas_segsum(seg_ids, values, num_segments,
+                              interpret=_interpret()).astype(values.dtype)
     return jax.ops.segment_sum(values, seg_ids, num_segments=num_segments)
 
 
@@ -137,13 +191,16 @@ def radix_hash_probe_dispatch(bk_codes, pk_codes, domain: int,
     slot; the result is ``(cnt_p, build_row, has_dup)`` — per probe row
     the number of matching build rows and the largest matching build-row
     id (−1 on miss), plus whether any live slot collides (the caller's
-    retry-to-sorted-core signal).  ``use_kernel`` is resolved outside jit
-    traces via :func:`use_pallas`, exactly like the segment-sum dispatch.
+    retry-to-sorted-core signal).  ``use_kernel`` is ``use_pallas(domain)``
+    resolved outside jit traces: the kernel works in exact int32 over any
+    code domain up to the 4096 gate.
     """
     if use_kernel:
         from ..kernels.segment_join.ops import radix_hash_probe
+        _KERNELS_TRACED.append("radix_hash_probe")
         return radix_hash_probe(bk_codes.astype(jnp.int32),
-                                pk_codes.astype(jnp.int32), domain)
+                                pk_codes.astype(jnp.int32), domain,
+                                interpret=_interpret())
     nb = bk_codes.shape[0]
     cnt = jnp.zeros((domain + 1,), jnp.int32).at[bk_codes].add(1)
     inv = jnp.zeros((domain + 1,), jnp.int32).at[bk_codes].max(
@@ -379,14 +436,17 @@ _AGG_DTYPE = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 @partial(jax.jit, static_argnames=("num_segments", "use_kernel"))
 def _join_aggregate(
     build_keys, build_vals, probe_keys, probe_vals, num_segments: int,
-    use_kernel: bool = False
+    use_kernel: Tuple[bool, bool, bool, bool] = (False,) * 4
 ):
-    seg_b = segment_sum_dispatch(build_vals, build_keys, num_segments, use_kernel)
+    """``use_kernel`` holds the segment-sum rule's answer for (build values,
+    build counts, probe values, probe counts)."""
+    kv_b, kc_b, kv_p, kc_p = use_kernel
+    seg_b = segment_sum_dispatch(build_vals, build_keys, num_segments, kv_b)
     cnt_b = segment_sum_dispatch(
-        jnp.ones_like(build_vals), build_keys, num_segments, use_kernel)
-    seg_p = segment_sum_dispatch(probe_vals, probe_keys, num_segments, use_kernel)
+        jnp.ones_like(build_vals), build_keys, num_segments, kc_b)
+    seg_p = segment_sum_dispatch(probe_vals, probe_keys, num_segments, kv_p)
     cnt_p = segment_sum_dispatch(
-        jnp.ones_like(probe_vals), probe_keys, num_segments, use_kernel)
+        jnp.ones_like(probe_vals), probe_keys, num_segments, kc_p)
     # SUM over join pairs of (b_val + p_val) decomposes along the key axis:
     #   sum_k [ cnt_p[k]*seg_b[k] + cnt_b[k]*seg_p[k] ]
     # and SUM of products contracts directly:  sum_k seg_b[k]*seg_p[k].
@@ -394,6 +454,13 @@ def _join_aggregate(
     sum_add = jnp.dot(seg_b, cnt_p) + jnp.dot(cnt_b, seg_p)
     sum_prod = jnp.dot(seg_b, seg_p)
     return sum_pairs, sum_add, sum_prod
+
+
+def host_max_abs(col: np.ndarray) -> Optional[int]:
+    """Exact max |value| of a host integer column (None when empty)."""
+    if len(col) == 0 or col.dtype.kind not in "iub":
+        return None
+    return max(abs(int(col.min())), abs(int(col.max())))
 
 
 def tensor_join_aggregate(
@@ -410,6 +477,15 @@ def tensor_join_aggregate(
     of ``build ⋈ probe``: pair count, Σ(b+p), Σ(b·p).  Both value columns are
     contracted at one explicit dtype (:data:`_AGG_DTYPE`).
     """
+    def rule(col: np.ndarray, counts: bool) -> bool:
+        if counts:
+            return segment_sum_uses_kernel(key_domain, len(col), np.bool_)
+        return segment_sum_uses_kernel(key_domain, len(col), col.dtype,
+                                       host_max_abs(col))
+
+    bv, pv = np.asarray(build[build_val]), np.asarray(probe[probe_val])
+    kernels = (rule(bv, False), rule(bv, True), rule(pv, False),
+               rule(pv, True))
     with Timer() as t:
         pairs, s_add, s_prod = _join_aggregate(
             jnp.asarray(build[key], jnp.int32),
@@ -417,7 +493,7 @@ def tensor_join_aggregate(
             jnp.asarray(probe[key], jnp.int32),
             jnp.asarray(probe[probe_val], _AGG_DTYPE),
             key_domain,
-            use_kernel=use_pallas(key_domain),
+            use_kernel=kernels,
         )
         pairs, s_add, s_prod = jax.device_get((pairs, s_add, s_prod))
         out = {
@@ -461,29 +537,10 @@ def _multikey_perm(key_cols: Tuple[jnp.ndarray, ...], valid, num_keys: int,
     return perm
 
 
-def _keys_fit_int32(key_cols) -> bool:
-    """Key columns the Pallas tile sorter can take without value loss: the
-    kernel casts to int32, so unsigned 32-bit (which would wrap negative)
-    needs headroom — only dtypes whose full range embeds in int32 qualify."""
-    def ok(dt):
-        if not jnp.issubdtype(dt, jnp.integer):
-            return False
-        info = jnp.iinfo(dt)
-        return info.min >= -(2**31) and info.max < 2**31
-    return all(ok(c.dtype) for c in key_cols)
-
-
 def sort_perm_device(key_cols: Tuple[jnp.ndarray, ...],
                      valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Sort permutation over key axes, Pallas-tiled when keys fit int32.
-
-    The Pallas path (bitonic VMEM tile runs + XLA merge) engages under
-    :func:`use_pallas` for int32-representable keys; otherwise the pure-jnp
-    stable LSD passes run.  Masked rows always sink to the tail.
-    """
-    if valid is None and use_pallas() and _keys_fit_int32(key_cols):
-        from ..kernels.multikey_sort.ops import multikey_sort_lsd_padded
-        return multikey_sort_lsd_padded(tuple(key_cols))
+    """Sort permutation over key axes: stable LSD passes, one per key axis.
+    Masked rows always sink to the tail."""
     return _multikey_perm(tuple(key_cols), valid, len(key_cols),
                           has_valid=valid is not None)
 

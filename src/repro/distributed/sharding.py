@@ -42,9 +42,9 @@ _MESH_CACHE: dict = {}
 
 
 def available_partitions() -> int:
-    """Device lanes a sharded fragment can fan out over — the local device
-    count (on CPU, the forced host-platform device count; see
-    ``tests/conftest.py`` / the CI ``XLA_FLAGS`` env var)."""
+    """Device lanes a sharded fragment can fan out over — the device count
+    this process sees (the test suite forces 8 CPU devices; see
+    ``tests/conftest.py``)."""
     return jax.device_count()
 
 
@@ -61,9 +61,9 @@ def relational_mesh(num_parts: int) -> Mesh:
     devs = jax.devices()
     if num_parts > len(devs):
         raise ValueError(
-            f"num_parts={num_parts} exceeds the {len(devs)} local devices; "
-            f"force a larger host mesh via XLA_FLAGS="
-            f"--xla_force_host_platform_device_count=N before importing jax")
+            f"num_parts={num_parts} exceeds the {len(devs)} "
+            f"{devs[0].platform} device(s) this process sees; request at "
+            f"most {len(devs)} partitions")
     mesh = _MESH_CACHE.get(num_parts)
     if mesh is None:
         mesh = Mesh(np.array(devs[:num_parts]), (PART_AXIS,))

@@ -1,7 +1,8 @@
 """Pallas TPU kernel: bitonic tile sort (stable via index tie-break).
 
-The tensor-path sort (§IV.B) runs stable per-axis passes; its run-generation
-stage sorts tiles that fit VMEM.  This kernel is that stage: each grid step
+The tensor-path sort (§IV.B) runs stable per-axis passes; this kernel was
+written as their run-generation stage (the engine no longer dispatches it:
+Mosaic cannot lower the 1-D partner gather, see ``ops.py``).  Each grid step
 sorts one tile of (key, payload) pairs entirely in VMEM with a bitonic
 network — log²(n)/2 vectorized compare-exchange sweeps, no HBM round trips.
 Stability comes from tie-breaking on the payload when payloads are the

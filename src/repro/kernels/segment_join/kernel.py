@@ -1,8 +1,8 @@
 """Pallas TPU kernels: segment-sum, radix partition, hash-table probe.
 
 Three VMEM-tiled kernels back the tensor engine's device joins and
-aggregates, all built on the same MXU-friendly idiom — data-dependent
-scatter/gather expressed as one-hot masked matmuls, which lowers
+aggregates, all built on the same idiom — data-dependent scatter/gather
+expressed as one-hot masks reduced by matmuls or lane sums, which lowers
 identically on TPU hardware and in interpret mode (the CPU fallback):
 
   * :func:`segment_sum_pallas` — per-tile one-hot matmul into a
@@ -39,30 +39,52 @@ __all__ = [
 ]
 
 
-def _segsum_kernel(seg_ref, val_ref, out_ref, *, num_segments):
-    t = pl.program_id(0)
+def _resident(t):
+    """Index map of an accumulator block that every grid step revisits.
+    Spelled from the traced step index: under the program's process-wide
+    jax_enable_x64 a literal 0 would be an int64 constant, which Mosaic
+    rejects."""
+    return (t * 0,)
 
-    @pl.when(t == 0)
+
+def _segsum_kernel(seg_ref, val_ref, out_ref, *, tblk, num_segments):
+    # Every constant carries an explicit 32-bit dtype: under the program's
+    # process-wide jax_enable_x64 a weakly typed Python scalar would put a
+    # 64-bit value in the kernel body, which Mosaic cannot lower.
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+        out_ref[...] = jnp.zeros(out_ref.shape, jnp.float32)
 
     seg = seg_ref[...]                      # [tblk] i32
     val = val_ref[...]                      # [tblk] f32
-    onehot = jnp.where(
-        seg[:, None] == jax.lax.iota(jnp.int32, num_segments)[None, :],
-        1.0, 0.0).astype(val.dtype)         # [tblk, S] built in VMEM
+    onehot = (seg[:, None] == jax.lax.broadcasted_iota(
+        jnp.int32, (tblk, num_segments), 1)).astype(jnp.float32)
+    # HIGHEST keeps the f32 operands whole on the MXU: integer-valued sums
+    # below 2**24 come out exact (the dispatch rule in tensor_engine only
+    # sends such sums here)
     out_ref[...] += jax.lax.dot_general(
         val[None, :], onehot, (((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype)[0]
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)[0]
+
+
+def segment_tile(num_segments: int) -> int:
+    """Row tile for the segment-sum kernel: a multiple of 1024 (XLA's tile
+    of a 1-D int32 array, which a block must match), and small enough that
+    the [tile, num_segments] f32 one-hot stays within 16 MiB of VMEM at the
+    4096-segment gate."""
+    return 2048 if num_segments <= 1024 else 1024
 
 
 def segment_sum_pallas(seg_ids, values, num_segments: int, *,
                        tblk: int = 2048, interpret: bool = False):
-    """seg_ids [N] i32 (< num_segments), values [N] → sums [num_segments]."""
+    """seg_ids [N] i32 (< num_segments), values [N] f32 → sums [num_segments]
+    f32."""
     n = seg_ids.shape[0]
     tblk = min(tblk, n)
     assert n % tblk == 0, (n, tblk)
-    kernel = functools.partial(_segsum_kernel, num_segments=num_segments)
+    kernel = functools.partial(_segsum_kernel, tblk=tblk,
+                               num_segments=num_segments)
     return pl.pallas_call(
         kernel,
         grid=(n // tblk,),
@@ -70,10 +92,10 @@ def segment_sum_pallas(seg_ids, values, num_segments: int, *,
             pl.BlockSpec((tblk,), lambda t: (t,)),
             pl.BlockSpec((tblk,), lambda t: (t,)),
         ],
-        out_specs=pl.BlockSpec((num_segments,), lambda t: (0,)),
-        out_shape=jax.ShapeDtypeStruct((num_segments,), values.dtype),
+        out_specs=pl.BlockSpec((num_segments,), _resident),
+        out_shape=jax.ShapeDtypeStruct((num_segments,), jnp.float32),
         interpret=interpret,
-    )(seg_ids, values)
+    )(seg_ids, values.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -81,53 +103,60 @@ def segment_sum_pallas(seg_ids, values, num_segments: int, *,
 # ---------------------------------------------------------------------------
 
 def _radix_rank_kernel(bkt_ref, pos_ref, cnt_ref, *, tblk, num_buckets):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        cnt_ref[...] = jnp.zeros(cnt_ref.shape, cnt_ref.dtype)
+        cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.int32)
 
     bkt = bkt_ref[...]                                     # [tblk] i32
-    onehot = (bkt[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (tblk, num_buckets), 1)).astype(jnp.int32)
-    # exclusive running count of this tile's rows per bucket → stable
-    # within-tile rank; the revisited cnt block carries the running
-    # cross-tile base (TPU grids execute sequentially).  Reductions pin
-    # dtype=int32: under jax_enable_x64 sum/cumsum otherwise promote to
-    # int64 and the int32 output-ref store rejects the value.
-    excl = jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - onehot
-    rank = jnp.sum(excl * onehot, axis=1, dtype=jnp.int32)  # [tblk]
+    hit = bkt[:, None] == jax.lax.broadcasted_iota(
+        jnp.int32, (tblk, num_buckets), 1)
+    onehot = hit.astype(jnp.float32)
+    # Exclusive running count of this tile's rows per bucket, as a strictly
+    # lower-triangular one-hot matmul (Mosaic has no cumsum).  Operands are
+    # 0/1 and every sum is at most tblk, so f32 on the MXU is exact.
+    lower = (jax.lax.broadcasted_iota(jnp.int32, (tblk, tblk), 1)
+             < jax.lax.broadcasted_iota(jnp.int32, (tblk, tblk), 0)
+             ).astype(jnp.float32)
+    excl = jax.lax.dot_general(lower, onehot, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+    rank = jnp.sum(excl * onehot, axis=1,
+                   dtype=jnp.float32).astype(jnp.int32)   # [tblk]
+    # the revisited cnt block carries the running cross-tile base (TPU
+    # grids execute sequentially)
     base = cnt_ref[...]                                    # [num_buckets]
-    pos_ref[...] = jnp.sum(onehot * base[None, :], axis=1,
-                           dtype=jnp.int32) + rank
-    cnt_ref[...] = base + jnp.sum(onehot, axis=0, dtype=jnp.int32)
+    pos_ref[...] = jnp.sum(jnp.where(hit, base[None, :], jnp.int32(0)),
+                           axis=1, dtype=jnp.int32) + rank
+    cnt_ref[...] = base + jnp.sum(hit.astype(jnp.int32), axis=0,
+                                  dtype=jnp.int32)
 
 
 def radix_rank_pallas(bucket_ids, num_buckets: int, *, tblk: int = 1024,
                       interpret: bool = False):
     """bucket_ids [N] i32 → ``(rank, counts)``: each row's stable rank
     within its bucket and the per-bucket histogram.  Rows with bucket ids
-    outside ``[0, num_buckets)`` contribute nothing (rank 0, uncounted) —
-    that is the padding contract."""
+    outside ``[0, num_buckets)`` are left out of the histogram and their
+    ranks carry no meaning — that is the padding contract."""
     n = bucket_ids.shape[0]
     tblk = min(tblk, n)
     assert n % tblk == 0, (n, tblk)
+    lanes = -(-num_buckets // 128) * 128   # one-hot width fills whole vregs
     kernel = functools.partial(_radix_rank_kernel, tblk=tblk,
-                               num_buckets=num_buckets)
-    return pl.pallas_call(
+                               num_buckets=lanes)
+    rank, counts = pl.pallas_call(
         kernel,
         grid=(n // tblk,),
         in_specs=[pl.BlockSpec((tblk,), lambda t: (t,))],
         out_specs=[
             pl.BlockSpec((tblk,), lambda t: (t,)),
-            pl.BlockSpec((num_buckets,), lambda t: (0,)),
+            pl.BlockSpec((lanes,), _resident),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((num_buckets,), jnp.int32),
+            jax.ShapeDtypeStruct((lanes,), jnp.int32),
         ],
         interpret=interpret,
     )(bucket_ids)
+    return rank, counts[:num_buckets]
 
 
 # ---------------------------------------------------------------------------
@@ -140,8 +169,8 @@ def _table_build_kernel(bk_ref, brow_ref, cnt_ref, inv_ref, *, tblk, dblk):
 
     @pl.when(i == 0)
     def _init():
-        cnt_ref[...] = jnp.zeros(cnt_ref.shape, cnt_ref.dtype)
-        inv_ref[...] = jnp.zeros(inv_ref.shape, inv_ref.dtype)
+        cnt_ref[...] = jnp.zeros(cnt_ref.shape, jnp.int32)
+        inv_ref[...] = jnp.zeros(inv_ref.shape, jnp.int32)
 
     codes = bk_ref[...]                                    # [tblk] i32
     lo = j * dblk
@@ -159,7 +188,7 @@ def _table_build_kernel(bk_ref, brow_ref, cnt_ref, inv_ref, *, tblk, dblk):
 
 
 def join_table_build_pallas(bk, brow, domain_pad: int, *, tblk: int = 1024,
-                            dblk: int = 512, interpret: bool = False):
+                            dblk: int = 1024, interpret: bool = False):
     """Build the tiled hash table: ``(cnt, inv)`` over ``[domain_pad]``
     slots, where ``cnt[c]`` counts build rows with code ``c`` and
     ``inv[c]`` holds the largest matching ``brow + 1`` (0 = empty slot).
@@ -193,29 +222,28 @@ def _table_probe_kernel(pk_ref, cnt_ref, inv_ref, cntp_ref, invp_ref, *,
 
     @pl.when(j == 0)
     def _init():
-        cntp_ref[...] = jnp.zeros(cntp_ref.shape, cntp_ref.dtype)
-        invp_ref[...] = jnp.zeros(invp_ref.shape, invp_ref.dtype)
+        cntp_ref[...] = jnp.zeros(cntp_ref.shape, jnp.int32)
+        invp_ref[...] = jnp.zeros(invp_ref.shape, jnp.int32)
 
     codes = pk_ref[...]                                    # [tblk] i32
     lo = j * dblk
 
     @pl.when((jnp.max(codes) >= lo) & (jnp.min(codes) < lo + dblk))
     def _accum():
-        local = codes - lo
-        onehot = (local[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (tblk, dblk), 1)).astype(jnp.int32)
-        # per-probe table gather as a one-hot matmul over the block; a
-        # probe's code lives in exactly one block so += never double-adds
-        cntp_ref[...] += jax.lax.dot_general(
-            onehot, cnt_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
-        invp_ref[...] += jax.lax.dot_general(
-            onehot, inv_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        hit = (codes - lo)[:, None] == jax.lax.broadcasted_iota(
+            jnp.int32, (tblk, dblk), 1)
+        # per-probe table gather as a masked lane reduction over the block
+        # (exact int32 on the VPU; a probe's code lives in exactly one
+        # block so += never double-adds)
+        zero = jnp.int32(0)
+        cntp_ref[...] += jnp.sum(jnp.where(hit, cnt_ref[...][None, :], zero),
+                                 axis=1, dtype=jnp.int32)
+        invp_ref[...] += jnp.sum(jnp.where(hit, inv_ref[...][None, :], zero),
+                                 axis=1, dtype=jnp.int32)
 
 
 def join_table_probe_pallas(pk, cnt, inv, *, tblk: int = 1024,
-                            dblk: int = 512, interpret: bool = False):
+                            dblk: int = 1024, interpret: bool = False):
     """Probe the tiled hash table: per probe row, ``(cnt_p, inv_p)`` =
     (matches in the build side, largest build-row-id + 1 or 0).  Codes ≥
     ``len(cnt)`` gather nothing (padding contract)."""

@@ -1,11 +1,15 @@
 """Jit'd wrappers: segment sum, radix partition and hash probe kernels.
 
 The raw Pallas kernels require row counts to be multiples of their tile
-sizes; these wrappers pad arbitrary relation sizes (segment id 0 with
-value 0 is sum-neutral; out-of-domain codes are the partition/probe
-padding contract) so the core engine can hand them real workloads.
-Value dtype is preserved (float64 works in interpret mode, which is the
-CPU fallback); TPU hardware runs float32.
+sizes (on the chip, multiples of 1024: XLA tiles a 1-D int32 array by
+1024 and a block must match it); these wrappers pad arbitrary relation
+sizes (segment id 0 with value 0 is sum-neutral; out-of-domain codes are
+the partition/probe padding contract) so the core engine can hand them
+real workloads.
+The segment sum runs in float32 on the chip and in interpret mode alike.
+``interpret`` defaults to False, the compiled TPU kernel; only an explicit
+``interpret=True`` (the engine's ``REPRO_PALLAS=1`` lane off the chip, and
+the parity tests) runs the interpreter.
 
 :func:`radix_hash_probe` is the full radix-join probe: both sides are
 radix-ordered by the top bits of their packed int32 codes (one
@@ -23,44 +27,35 @@ import jax
 import jax.numpy as jnp
 
 from .kernel import (join_table_build_pallas, join_table_probe_pallas,
-                     radix_rank_pallas, segment_sum_pallas)
+                     radix_rank_pallas, segment_sum_pallas, segment_tile)
 
 __all__ = ["segment_sum", "join_aggregate_kernel", "radix_partition",
            "radix_hash_probe"]
 
 
-def _auto_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
-
-
 @partial(jax.jit, static_argnames=("num_segments", "tblk", "interpret"))
-def segment_sum(seg_ids, values, num_segments: int, tblk: int = 2048,
-                interpret=None):
-    interpret = _auto_interpret(interpret)
+def segment_sum(seg_ids, values, num_segments: int, tblk: int = None,
+                interpret: bool = False):
+    """Per-segment f32 sums.  Exact only for integer-valued inputs whose
+    every partial sum stays below 2**24 in magnitude — the caller's
+    dispatch rule (``tensor_engine.segment_sum_dispatch``) enforces that."""
     n = seg_ids.shape[0]
     if n == 0:
-        dt = values.dtype if values.dtype.kind == "f" else jnp.float32
-        return jnp.zeros((num_segments,), dt)
-    tblk = min(tblk, n)
-    vals = values
-    if vals.dtype == jnp.float64 and not interpret:
-        vals = vals.astype(jnp.float32)  # TPU hardware path has no f64
-    elif vals.dtype.kind not in "f":
-        vals = vals.astype(jnp.float32)
-    pad = (-n) % max(1, tblk)
+        return jnp.zeros((num_segments,), jnp.float32)
+    tblk = tblk or segment_tile(num_segments)
+    vals = values.astype(jnp.float32)
+    pad = (-n) % tblk
     seg = seg_ids.astype(jnp.int32)
     if pad:
         seg = jnp.concatenate([seg, jnp.zeros((pad,), jnp.int32)])
-        vals = jnp.concatenate([vals, jnp.zeros((pad,), vals.dtype)])
+        vals = jnp.concatenate([vals, jnp.zeros((pad,), jnp.float32)])
     return segment_sum_pallas(seg, vals, num_segments,
                               tblk=tblk, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("num_segments", "interpret"))
 def join_aggregate_kernel(build_keys, build_vals, probe_keys, probe_vals,
-                          num_segments: int, interpret=None):
+                          num_segments: int, interpret: bool = False):
     """Σ over (virtual) join pairs of b·p — join output never materialized."""
     sb = segment_sum(build_keys, build_vals, num_segments, interpret=interpret)
     sp = segment_sum(probe_keys, probe_vals, num_segments, interpret=interpret)
@@ -74,17 +69,15 @@ def join_aggregate_kernel(build_keys, build_vals, probe_keys, probe_vals,
 
 @partial(jax.jit, static_argnames=("num_buckets", "tblk", "interpret"))
 def radix_partition(bucket_ids, num_buckets: int, tblk: int = 1024,
-                    interpret=None):
+                    interpret: bool = False):
     """Stable partition positions: ``(dest, counts)`` where ``dest[i]`` is
     row ``i``'s position in partition-major order (rows of the same bucket
     keep their relative order) and ``counts`` is the bucket histogram.
     ``bucket_ids`` must lie in ``[0, num_buckets)``."""
-    interpret = _auto_interpret(interpret)
     n = bucket_ids.shape[0]
     if n == 0:
         return (jnp.zeros((0,), jnp.int32),
                 jnp.zeros((num_buckets,), jnp.int32))
-    tblk = min(tblk, n)
     b = bucket_ids.astype(jnp.int32)
     pad = (-n) % tblk
     if pad:
@@ -106,8 +99,8 @@ def _order(arr, dest, n):
 
 
 @partial(jax.jit, static_argnames=("domain", "tblk", "dblk", "interpret"))
-def radix_hash_probe(bk, pk, domain: int, tblk: int = 1024, dblk: int = 512,
-                     interpret=None):
+def radix_hash_probe(bk, pk, domain: int, tblk: int = 1024, dblk: int = 1024,
+                     interpret: bool = False):
     """Radix-partitioned hash-join probe in the packed code domain.
 
     ``bk``/``pk`` are int32 codes in ``[0, domain]`` — slot ``domain`` is
@@ -120,7 +113,6 @@ def radix_hash_probe(bk, pk, domain: int, tblk: int = 1024, dblk: int = 512,
     miss), plus whether any *live* slot holds more than one build row
     (the caller's retry-to-sorted-core signal).
     """
-    interpret = _auto_interpret(interpret)
     nb, np_ = bk.shape[0], pk.shape[0]
     nblocks = -(-(domain + 1) // dblk)
     dpad = nblocks * dblk
@@ -141,7 +133,7 @@ def radix_hash_probe(bk, pk, domain: int, tblk: int = 1024, dblk: int = 512,
                                interpret=interpret)
     pk_ord, _ = _order(pk, pdest, np_)
     # 2. build the domain-tiled table (pad rows use code dpad: no block)
-    bpad = (-nb) % min(tblk, nb)
+    bpad = (-nb) % tblk
     if bpad:
         bk_ord = jnp.concatenate([bk_ord,
                                   jnp.full((bpad,), dpad, jnp.int32)])
@@ -150,7 +142,7 @@ def radix_hash_probe(bk, pk, domain: int, tblk: int = 1024, dblk: int = 512,
                                            tblk=tblk, dblk=dblk,
                                            interpret=interpret)
     # 3. probe in radix order, then gather back to original row order
-    ppad = (-np_) % min(tblk, np_)
+    ppad = (-np_) % tblk
     if ppad:
         pk_ord = jnp.concatenate([pk_ord,
                                   jnp.full((ppad,), dpad, jnp.int32)])

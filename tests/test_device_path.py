@@ -326,8 +326,9 @@ def test_pallas_multikey_sort_padded_matches_lexsort():
 
 
 def test_engine_parity_with_pallas_forced(monkeypatch):
-    """REPRO_PALLAS=1 routes the engine's segment/sort inner loops through
-    the Pallas kernels (interpret mode on CPU) with identical results."""
+    """REPRO_PALLAS=1 routes the engine's segment sums through the Pallas
+    kernel (interpret mode on CPU) where its exactness rule admits them,
+    with identical results; sorts stay on the jnp LSD passes."""
     monkeypatch.setenv("REPRO_PALLAS", "1")
     rng = np.random.default_rng(41)
     rel = Relation({"k": rng.integers(0, 16, 512).astype(np.int64),
@@ -336,8 +337,8 @@ def test_engine_parity_with_pallas_forced(monkeypatch):
     ten, _ = group_aggregate_tensor(rel, "k", {"v": "sum"})
     lin, _ = group_aggregate_linear(rel, "k", {"v": "sum"}, 1 << 30)
     assert ten.sort_canonical().equals(lin.sort_canonical())
-    # int32 sort keys dispatch to the bitonic tile kernel
-    from repro.core.tensor_engine import sort_perm_device
+    from repro.core.tensor_engine import kernels_traced, sort_perm_device
+    assert kernels_traced().get("segment_sum", 0) > 0
     keys = (jnp.asarray(rng.integers(0, 7, 300), jnp.int32),)
     perm = np.asarray(sort_perm_device(keys))
     np.testing.assert_array_equal(np.asarray(keys[0])[perm],
@@ -613,12 +614,12 @@ def test_pallas_segment_sum_empty_input(monkeypatch):
 
 
 def test_pallas_sort_gate_rejects_uint32():
-    from repro.core.tensor_engine import _keys_fit_int32
-    assert _keys_fit_int32((jnp.zeros(4, jnp.int32),))
-    assert _keys_fit_int32((jnp.zeros(4, jnp.int16),))
-    assert not _keys_fit_int32((jnp.zeros(4, jnp.uint32),))  # would wrap
-    assert not _keys_fit_int32((jnp.zeros(4, jnp.int64),))
-    assert not _keys_fit_int32((jnp.zeros(4, jnp.float32),))
+    from repro.kernels.multikey_sort.ops import keys_fit_int32
+    assert keys_fit_int32((jnp.zeros(4, jnp.int32),))
+    assert keys_fit_int32((jnp.zeros(4, jnp.int16),))
+    assert not keys_fit_int32((jnp.zeros(4, jnp.uint32),))  # would wrap
+    assert not keys_fit_int32((jnp.zeros(4, jnp.int64),))
+    assert not keys_fit_int32((jnp.zeros(4, jnp.float32),))
 
 
 def test_group_aggregate_tensor_float_keys():

@@ -553,3 +553,47 @@ def test_select_subrelation_query_transfers_zero_when_parent_warm():
     warm = ex.execute(plan(build.select(["k", "v"]), probe.select(["k", "w"])))
     assert warm.scalar == cold.scalar
     assert warm.total_h2d_bytes == 0
+
+
+# ---------------------------------------------------------------------------
+# Only a host-only predicate moves a fragment off the device
+# ---------------------------------------------------------------------------
+
+def _join_sum_session(policy="tensor"):
+    rng = np.random.default_rng(3)
+    sess = Session(work_mem=1 << 30, policy=policy)
+    sess.register("orders", {"uid": rng.integers(0, 64, 2000),
+                             "w": rng.integers(-9, 9, 2000)})
+    sess.register("users", {"uid": np.arange(64),
+                            "region": rng.integers(0, 4, 64)})
+    return sess
+
+
+def test_fused_device_error_propagates(monkeypatch):
+    """A compile, lowering or runtime failure of the fused program is not a
+    reason to re-run the query on the host: it reaches the caller."""
+    import repro.core.fused as fused
+
+    def fail(*_a, **_k):
+        raise RuntimeError("device program failed")
+
+    monkeypatch.setattr(fused, "run_fused", fail)
+    sess = _join_sum_session()
+    q = (sess.table("orders").join("users", on="uid")
+         .filter(col("w") > 0).aggregate("w", "sum"))
+    with pytest.raises(RuntimeError, match="device program failed"):
+        q.collect()
+
+
+def test_untraceable_predicate_takes_generic_walk():
+    """A predicate that needs host numpy cannot trace into the fused
+    program; the query still answers, on the generic walk."""
+    sess = _join_sum_session()
+    host_only = lambda r: np.asarray(r["w"]) > 0  # noqa: E731
+    res = (sess.table("orders").join("users", on="uid")
+           .filter(host_only).aggregate("w", "sum")).collect()
+    ops = [m.op for m in res.metrics]
+    assert "fused_pipeline" not in ops
+    ref = (_join_sum_session("linear").table("orders").join("users", on="uid")
+           .filter(host_only).aggregate("w", "sum")).collect()
+    assert res.scalar == ref.scalar

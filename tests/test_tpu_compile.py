@@ -1,0 +1,99 @@
+"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e.
+
+JAX's TPU compiler compiles for a chip that is described, not attached, so
+these tests run on a CPU host and catch what the interpret-mode parity
+tests cannot: Mosaic lowering gaps (``cumsum``, 1-D gathers), 64-bit values
+in a kernel body under the program's ``jax_enable_x64``, and blocks that
+miss XLA's tiling.  Widths are the real ones: 2^23 rows, 2048 and 4096
+segments or code domains — the gate in ``tensor_engine.use_pallas``.
+
+The topology is described inside a fixture: only the worker that runs this
+file loads the TPU library, and a host that cannot describe it skips.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import tensor_engine
+from repro.kernels.segment_join import ops
+
+ROWS = 1 << 23
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    # the TPU compiler logs under /tmp unless told otherwise
+    log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler on this host
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without one: keep these out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    if log_dir is None:
+        os.environ.pop("TPU_LOG_DIR", None)
+    else:
+        os.environ["TPU_LOG_DIR"] = log_dir
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel
+    return compiled
+
+
+@pytest.mark.parametrize("num_segments", [2048, 4096])
+def test_segment_sum_compiles(one_chip, num_segments):
+    _compile(lambda s, v: ops.segment_sum(s, v, num_segments), one_chip,
+             ((ROWS,), jnp.int32), ((ROWS,), jnp.float32))
+
+
+def test_radix_partition_compiles(one_chip):
+    _compile(lambda b: ops.radix_partition(b, 5), one_chip,
+             ((ROWS,), jnp.int32))
+
+
+@pytest.mark.parametrize("domain", [2048, 4096])
+def test_radix_hash_probe_compiles(one_chip, domain):
+    _compile(lambda b, p: ops.radix_hash_probe(b, p, domain), one_chip,
+             ((domain,), jnp.int32), ((ROWS,), jnp.int32))
+
+
+@pytest.mark.parametrize("max_abs,takes_kernel", [
+    (50, False),   # SSB-like measure: 2^23 * 50 > 2^24, jnp core
+    (1, True),     # 0/1 flags: 2^23 * 1 < 2^24, the f32 kernel is exact
+])
+def test_segment_sum_rule_exact_at_scale(monkeypatch, max_abs, takes_kernel):
+    """The dispatch rule picks the kernel only where its f32 sum is exact,
+    and the path it picks matches numpy exactly at 2^23 rows (the kernel
+    in interpret mode here; the chip runs the same f32 arithmetic)."""
+    monkeypatch.setenv("REPRO_PALLAS", "1")
+    rng = np.random.default_rng(max_abs)
+    seg = rng.integers(0, 2048, ROWS)
+    vals = rng.integers(-max_abs, max_abs + 1, ROWS).astype(np.int64)
+    rule = tensor_engine.segment_sum_uses_kernel(2048, ROWS, vals.dtype,
+                                                 max_abs)
+    assert rule is takes_kernel
+    got = tensor_engine.segment_sum_dispatch(
+        jnp.asarray(vals, jnp.float64), jnp.asarray(seg, jnp.int32), 2048,
+        rule)
+    want = np.bincount(seg, weights=vals, minlength=2048)
+    np.testing.assert_array_equal(np.asarray(got), want)
