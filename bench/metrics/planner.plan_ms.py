@@ -1,0 +1,16 @@
+"""Front end: host time per query spent planning and choosing paths, in ms.
+
+Mean over the window's queries of the seconds of the engine's ``rel.plan``
+and ``rel.select`` spans, as each query's ``QueryResult.trace`` records
+them (``repro.core.tracing``).  No reading where the run's query records
+carry no trace.  Moves ``query_p50_s``.
+"""
+
+
+def read(run):
+    traces = [getattr(q, "trace", None) for q in run.queries]
+    if not traces or None in traces:
+        return None
+    return 1e3 * sum(t.seconds.get("rel.plan", 0.0)
+                     + t.seconds.get("rel.select", 0.0)
+                     for t in traces) / len(traces)

@@ -73,6 +73,7 @@ from .server import (FailedQuery, QueryServer, ServeReport, ServedQuery,
 from .session import Query, Session
 from .slo import ArrivalProcess, TenantClass
 from .spill import SpillManager
+from .tracing import QueryTrace
 from .tier import (TierConfig, TierLedger, TierManager, TierStats,
                    decode_column, encode_column)
 from .table_cache import (KeyStats, get_device_columns, key_stats,
@@ -105,7 +106,7 @@ __all__ = [
     "PHYSICAL_NODES", "PathSelector", "PreemptToken", "PreemptedError",
     "PressureQuote", "Program", "Project",
     "ProportionalShareGrantPolicy", "Query", "QueryRejected",
-    "QueryResult", "QueryServer", "Relation", "Reservation",
+    "QueryResult", "QueryServer", "QueryTrace", "Relation", "Reservation",
     "ResourceBroker",
     "ResourceRequest", "RetryPolicy",
     "RuntimeProfile", "Scan", "ServeReport", "ServedQuery", "Session",

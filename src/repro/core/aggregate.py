@@ -252,7 +252,7 @@ def _group_reduce_jit():
     global _GROUP_REDUCE_JIT
     if _GROUP_REDUCE_JIT is None:
         _GROUP_REDUCE_JIT = jax.jit(
-            _group_reduce_impl,
+            jax.named_scope("op.group_by")(_group_reduce_impl),
             static_argnames=("fns", "num_segments", "use_kernel"))
     return _GROUP_REDUCE_JIT
 

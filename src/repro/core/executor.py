@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .device_relation import DeviceRelation
 from .faults import (DeviceDispatchError, FaultInjector, PreemptedError,
                      RetryPolicy, TransientError)
@@ -121,6 +122,12 @@ class Project:
 # legacy detection both key off this one tuple (add new nodes HERE)
 PHYSICAL_NODES = (Scan, Filter, Join, Sort, Aggregate, GroupBy, Project)
 
+# host span of each operator of the generic walk (scans and projections
+# are structural and take none)
+_OP_SPANS = {Filter: "rel.op.filter", Join: "rel.op.join",
+             Sort: "rel.op.sort", GroupBy: "rel.op.group_by",
+             Aggregate: "rel.op.aggregate"}
+
 # Process-wide registry of per-operator device shape signatures whose jitted
 # programs have (very likely) already compiled — jax's compile cache is
 # process-global, so freshness is too.  Exact row counts on purpose: the
@@ -152,6 +159,10 @@ class QueryResult:
     scalar: Optional[float]
     metrics: List[OpMetrics]
     decisions: List[Decision]
+    # spans and dispatch counters of the whole query (repro.core.tracing);
+    # empty for results built outside a query boundary
+    trace: tracing.QueryTrace = dataclasses.field(
+        default_factory=tracing.QueryTrace)
 
     @property
     def total_wall_s(self) -> float:
@@ -312,20 +323,22 @@ class Executor:
         path."""
         if self.selector.force is not None:
             return None, None, None
-        rsv = None
-        if self.broker.governor is not None:
-            req = min(self.work_mem, max(1, int(need_bytes)))
-            rsv = self.broker.reserve(ResourceRequest("memory",
-                                                      need_bytes=req))
-            mem = rsv.quote
-        else:
-            # ungoverned: a synthetic full-grant quote at the EXECUTOR's
-            # work_mem, preserving the pre-broker contract that decisions
-            # are priced against the executor's budget even when the
-            # selector was constructed with a different one
-            mem = PressureQuote("memory", self.work_mem, 0.0, 0, False)
-        dev = self.broker.price(ResourceRequest("device",
-                                                lanes=max(1, int(lanes))))
+        with tracing.span("rel.select"):
+            rsv = None
+            if self.broker.governor is not None:
+                req = min(self.work_mem, max(1, int(need_bytes)))
+                rsv = self.broker.reserve(ResourceRequest("memory",
+                                                          need_bytes=req))
+                mem = rsv.quote
+            else:
+                # ungoverned: a synthetic full-grant quote at the
+                # EXECUTOR's work_mem, preserving the pre-broker contract
+                # that decisions are priced against the executor's budget
+                # even when the selector was constructed with a different
+                # one
+                mem = PressureQuote("memory", self.work_mem, 0.0, 0, False)
+            dev = self.broker.price(ResourceRequest(
+                "device", lanes=max(1, int(lanes))))
         return mem, dev, rsv
 
     @contextlib.contextmanager
@@ -533,7 +546,9 @@ class Executor:
             with _WARM_SIG_LOCK:
                 fresh = sig not in _WARM_SIGS
             if fresh:
-                yield None
+                tracing.count(fresh_programs=1, dispatches=1)
+                with tracing.span("rel.compile"):
+                    yield None
                 # registered only on normal completion: a run that raised
                 # may never have finished compiling, and treating the
                 # shape as warm would put the retry's compile INSIDE an
@@ -543,6 +558,7 @@ class Executor:
                         _WARM_SIGS.clear()
                     _WARM_SIGS.add(sig)
                 return
+        tracing.count(dispatches=1)
         lease = self.broker.device_lease(batch_key="per-op")
         try:
             yield lease
@@ -568,6 +584,12 @@ class Executor:
             m.batched = m.batched or lease.batched
 
     def execute(self, plan) -> QueryResult:
+        with tracing.query() as trace:
+            res = self._execute(plan)
+        res.trace = trace
+        return res
+
+    def _execute(self, plan) -> QueryResult:
         if not isinstance(plan, PHYSICAL_NODES):
             # logical IR (or a fluent Query): route through the rewrite
             # planner, which chains physical fragments back through this
@@ -762,9 +784,10 @@ class Executor:
             self.selector.model.hash_need_bytes(len(build)),
             lanes=self.max_shards)
         try:
-            decision = self.selector.choose_fragment(
-                spec, build, probe, mem_quote=mem_q, dev_quote=dev_q,
-                max_shards=self.max_shards)
+            with tracing.span("rel.select"):
+                decision = self.selector.choose_fragment(
+                    spec, build, probe, mem_quote=mem_q, dev_quote=dev_q,
+                    max_shards=self.max_shards)
             if decision.path != "tensor":
                 return None  # generic walk re-quotes (and re-reserves) itself
             decisions.append(decision)
@@ -846,6 +869,12 @@ class Executor:
         and their walls would carry exactly the contention noise the
         ROADMAP flagged for profile feedback.
         """
+        if not isinstance(out, (DeviceRelation, _DeviceScalar)):
+            return out
+        with tracing.span("rel.op.materialize"):
+            return self._materialize(out, metrics)
+
+    def _materialize(self, out, metrics):
         if isinstance(out, DeviceRelation):
             sig = ("materialize", out.num_physical_rows, out.names,
                    out.valid is None)
@@ -876,7 +905,6 @@ class Executor:
                 raise ValueError(
                     f"{out.fn} over an empty result has no identity")
             return val
-        return out
 
     @staticmethod
     def _lower_for_linear(*rels):
@@ -912,6 +940,15 @@ class Executor:
 
     # -- node dispatch -----------------------------------------------------
     def _exec(self, node, metrics, decisions, mgr):
+        """Run ``node`` under its ``rel.op.<op>`` span; the spans of its
+        inputs' operators nest inside it."""
+        name = _OP_SPANS.get(type(node))
+        if name is None:
+            return self._exec_node(node, metrics, decisions, mgr)
+        with tracing.span(name):
+            return self._exec_node(node, metrics, decisions, mgr)
+
+    def _exec_node(self, node, metrics, decisions, mgr):
         if isinstance(node, Scan):
             return node.relation
         if isinstance(node, Project):
@@ -960,8 +997,10 @@ class Executor:
                 return out, m
 
             try:
-                decision = self._decide(self.selector.choose_join(
-                    build, probe, node.key, mem_quote=mem_q, dev_quote=dev_q))
+                with tracing.span("rel.select"):
+                    decision = self._decide(self.selector.choose_join(
+                        build, probe, node.key, mem_quote=mem_q,
+                        dev_quote=dev_q))
                 decisions.append(decision)
                 if decision.path == "tensor":
                     out, m = join_tensor()
@@ -1046,8 +1085,9 @@ class Executor:
                 return out, m
 
             try:
-                decision = self._decide(self.selector.choose_sort(
-                    child, node.keys, mem_quote=mem_q, dev_quote=dev_q))
+                with tracing.span("rel.select"):
+                    decision = self._decide(self.selector.choose_sort(
+                        child, node.keys, mem_quote=mem_q, dev_quote=dev_q))
                 decisions.append(decision)
                 if decision.path == "tensor":
                     out, m = sort_tensor()
@@ -1111,8 +1151,9 @@ class Executor:
                 self.selector.model.sort_need_bytes(
                     len(child), child.row_bytes()))
             try:
-                decision = self._decide(self.selector.choose_sort(
-                    child, [node.key], mem_quote=mem_q, dev_quote=dev_q))
+                with tracing.span("rel.select"):
+                    decision = self._decide(self.selector.choose_sort(
+                        child, [node.key], mem_quote=mem_q, dev_quote=dev_q))
                 decisions.append(decision)
                 if decision.path == "tensor":
                     dev_c, up_c, log_c = self._to_device(child)

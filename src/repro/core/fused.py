@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .codec_device import decode_device, dict_bucket
 from .metrics import OpMetrics, SpillAccount, Timer
 from .relation import Relation
@@ -245,16 +246,18 @@ class _JoinView:
             # named b_<x> and the build side has x, the engine's join
             # (a dict merge that assigns build columns last) serves the
             # BUILD column under that name — the view must agree
-            if (name.startswith("b_") and name[2:] in self._bcols
-                    and name[2:] != self._key):
-                col = jnp.take(self._bcols[name[2:]], self._bidx)
-                dec = self._bdec.get(name[2:])
-            elif name in self._pcols:
-                col = jnp.take(self._pcols[name], self._pidx)
-                dec = self._pdec.get(name)
-            else:
-                raise KeyError(name)
-            self._cache[name] = col if dec is None else dec(col)
+            with jax.named_scope("gather"):
+                if (name.startswith("b_") and name[2:] in self._bcols
+                        and name[2:] != self._key):
+                    col = jnp.take(self._bcols[name[2:]], self._bidx)
+                    dec = self._bdec.get(name[2:])
+                elif name in self._pcols:
+                    col = jnp.take(self._pcols[name], self._pidx)
+                    dec = self._pdec.get(name)
+                else:
+                    raise KeyError(name)
+            with jax.named_scope("decode"):
+                self._cache[name] = col if dec is None else dec(col)
         return self._cache[name]
 
 
@@ -338,36 +341,54 @@ def pipeline_cache_clear() -> None:
     _CACHE.clear()
 
 
+def _prefix(x, reducer):
+    """Inclusive prefix sum (``reducer`` ``lax.add``) or running max
+    (``lax.max``) of a 1-D integer array: the reduce-window that
+    ``jnp.cumsum`` and ``lax.cummax`` lower to, written out.  Those
+    primitives lower through a separate function, and its inlining leaves
+    their ops without the caller's ``jax.named_scope``; written here, the
+    same instructions keep the scope."""
+    n = x.shape[0]
+    init = 0 if reducer is jax.lax.add else jnp.iinfo(x.dtype).min
+    return jax.lax.reduce_window(x, np.array(init, x.dtype), reducer, (n,),
+                                 (1,), [(n - 1, 0)])
+
+
 def _join_sorted(bk, pk, n_build, n_probe, capacity):
     """General join core: sorted coordinate alignment (one device sort)."""
     B = bk.shape[0]
     P = pk.shape[0]
     iota_b = jnp.arange(B)
     iota_p = jnp.arange(P)
-    # bucket padding rows sort to the tail and can never match
-    bk_m = jnp.where(iota_b < n_build, bk, _I64_MAX)
-    order = jnp.argsort(bk_m, stable=True)
-    sk = jnp.take(bk_m, order)
-    left = jnp.searchsorted(sk, pk, side="left")
-    right = jnp.searchsorted(sk, pk, side="right")
-    counts = right - left
-    # padded probe rows contribute nothing; a real probe key equal to the
-    # int64 sentinel would false-match padded build rows, so it is
-    # excluded (documented key-domain contract)
-    counts = jnp.where((iota_p < n_probe) & (pk != _I64_MAX), counts, 0)
-    ends = jnp.cumsum(counts)
-    starts = ends - counts
-    total = ends[-1]
-    slot = jnp.arange(capacity, dtype=ends.dtype)
-    # expansion by scan, not binary search: scatter each matched probe row's
-    # index at its start slot, then forward-fill with a running max
-    seed_slots = jnp.full((capacity + 1,), -1, jnp.int64)
-    tgt = jnp.where(counts > 0, jnp.minimum(starts, capacity), capacity)
-    seeded = seed_slots.at[tgt].max(iota_p)[:capacity]
-    probe_idx = jnp.maximum(jax.lax.cummax(seeded), 0)
-    build_pos = left[probe_idx] + (slot - starts[probe_idx])
-    build_idx = jnp.take(order, jnp.clip(build_pos, 0, B - 1))
-    valid = slot < total
+    with jax.named_scope("join.sorted.sort"):
+        # bucket padding rows sort to the tail and can never match
+        bk_m = jnp.where(iota_b < n_build, bk, _I64_MAX)
+        order = jnp.argsort(bk_m, stable=True)
+        sk = jnp.take(bk_m, order)
+    with jax.named_scope("join.sorted.search"):
+        left = jnp.searchsorted(sk, pk, side="left")
+        right = jnp.searchsorted(sk, pk, side="right")
+        counts = right - left
+        # padded probe rows contribute nothing; a real probe key equal to
+        # the int64 sentinel would false-match padded build rows, so it is
+        # excluded (documented key-domain contract)
+        counts = jnp.where((iota_p < n_probe) & (pk != _I64_MAX), counts, 0)
+    with jax.named_scope("join.prefix_sum"):
+        ends = _prefix(counts, jax.lax.add)
+        starts = ends - counts
+        total = ends[-1]
+    with jax.named_scope("join.expand"):
+        slot = jnp.arange(capacity, dtype=ends.dtype)
+        # expansion by scan, not binary search: scatter each matched probe
+        # row's index at its start slot, then forward-fill with a running
+        # max
+        seed_slots = jnp.full((capacity + 1,), -1, jnp.int64)
+        tgt = jnp.where(counts > 0, jnp.minimum(starts, capacity), capacity)
+        seeded = seed_slots.at[tgt].max(iota_p)[:capacity]
+        probe_idx = jnp.maximum(_prefix(seeded, jax.lax.max), 0)
+        build_pos = left[probe_idx] + (slot - starts[probe_idx])
+        build_idx = jnp.take(order, jnp.clip(build_pos, 0, B - 1))
+        valid = slot < total
     has_dup = jnp.asarray(False)
     return build_idx, probe_idx, valid, total, has_dup
 
@@ -386,23 +407,26 @@ def _join_sorted_run(sk, pk, n_probe, capacity):
     B = sk.shape[0]
     P = pk.shape[0]
     iota_p = jnp.arange(P)
-    left = jnp.searchsorted(sk, pk, side="left")
-    right = jnp.searchsorted(sk, pk, side="right")
-    # sentinel-padded probe rows contribute nothing (same key-domain
-    # contract as the single-device core)
-    counts = jnp.where((iota_p < n_probe) & (pk != _I64_MAX),
-                       right - left, 0)
-    ends = jnp.cumsum(counts)
-    starts = ends - counts
-    total = ends[-1]
-    slot = jnp.arange(capacity, dtype=ends.dtype)
-    seed_slots = jnp.full((capacity + 1,), -1, jnp.int64)
-    tgt = jnp.where(counts > 0, jnp.minimum(starts, capacity), capacity)
-    seeded = seed_slots.at[tgt].max(iota_p)[:capacity]
-    probe_idx = jnp.maximum(jax.lax.cummax(seeded), 0)
-    build_pos = left[probe_idx] + (slot - starts[probe_idx])
-    build_idx = jnp.clip(build_pos, 0, B - 1)
-    valid = slot < total
+    with jax.named_scope("join.sorted.search"):
+        left = jnp.searchsorted(sk, pk, side="left")
+        right = jnp.searchsorted(sk, pk, side="right")
+        # sentinel-padded probe rows contribute nothing (same key-domain
+        # contract as the single-device core)
+        counts = jnp.where((iota_p < n_probe) & (pk != _I64_MAX),
+                           right - left, 0)
+    with jax.named_scope("join.prefix_sum"):
+        ends = _prefix(counts, jax.lax.add)
+        starts = ends - counts
+        total = ends[-1]
+    with jax.named_scope("join.expand"):
+        slot = jnp.arange(capacity, dtype=ends.dtype)
+        seed_slots = jnp.full((capacity + 1,), -1, jnp.int64)
+        tgt = jnp.where(counts > 0, jnp.minimum(starts, capacity), capacity)
+        seeded = seed_slots.at[tgt].max(iota_p)[:capacity]
+        probe_idx = jnp.maximum(_prefix(seeded, jax.lax.max), 0)
+        build_pos = left[probe_idx] + (slot - starts[probe_idx])
+        build_idx = jnp.clip(build_pos, 0, B - 1)
+        valid = slot < total
     return build_idx, probe_idx, valid, total
 
 
@@ -431,38 +455,50 @@ def _join_dense(bk, pk, n_build, n_probe, capacity, domain: int, kmin,
     P = pk.shape[0]
     iota_b = jnp.arange(B)
     iota_p = jnp.arange(P)
-    bk0 = bk - kmin
-    b_live = iota_b < n_build
-    bk0c = jnp.where(b_live & (bk0 >= 0) & (bk0 < domain), bk0, domain)
-    pk0 = pk - kmin
-    p_live = (iota_p < n_probe) & (pk0 >= 0) & (pk0 < domain)
-    pk0c = jnp.where(p_live, pk0, domain)
+    with jax.named_scope("join.dense.build"):
+        bk0 = bk - kmin
+        b_live = iota_b < n_build
+        bk0c = jnp.where(b_live & (bk0 >= 0) & (bk0 < domain), bk0, domain)
+    with jax.named_scope("join.dense.probe"):
+        pk0 = pk - kmin
+        p_live = (iota_p < n_probe) & (pk0 >= 0) & (pk0 < domain)
+        pk0c = jnp.where(p_live, pk0, domain)
     if use_kernel:
-        cnt_p, brow, has_dup = radix_hash_probe_dispatch(
-            bk0c.astype(jnp.int32), pk0c.astype(jnp.int32), domain, True)
-        matched = p_live & (cnt_p > 0)
-        ends = jnp.cumsum(matched.astype(jnp.int64))
+        with jax.named_scope("join.dense.pallas"):
+            cnt_p, brow, has_dup = radix_hash_probe_dispatch(
+                bk0c.astype(jnp.int32), pk0c.astype(jnp.int32), domain,
+                True)
+            matched = p_live & (cnt_p > 0)
+        with jax.named_scope("join.prefix_sum"):
+            ends = _prefix(matched.astype(jnp.int64), jax.lax.add)
+            total = ends[-1]
+        with jax.named_scope("join.expand"):
+            slot = jnp.arange(capacity, dtype=jnp.int64)
+            pos = jnp.where(matched, jnp.minimum(ends - 1, capacity - 1),
+                            capacity)
+            probe_idx = jnp.zeros((capacity + 1,),
+                                  jnp.int64).at[pos].max(iota_p)[:capacity]
+            build_idx = jnp.take(jnp.maximum(brow, 0).astype(jnp.int64),
+                                 probe_idx)
+            valid = slot < total
+        return build_idx, probe_idx, valid, total, has_dup
+    with jax.named_scope("join.dense.build"):
+        cnt = jnp.zeros((domain + 1,), jnp.int32).at[bk0c].add(1)
+        has_dup = cnt[:domain].max() > 1
+        inv = jnp.zeros((domain + 1,), jnp.int64).at[bk0c].set(iota_b)
+    with jax.named_scope("join.dense.probe"):
+        matched = p_live & (cnt[pk0c] > 0)
+    with jax.named_scope("join.prefix_sum"):
+        ends = _prefix(matched.astype(jnp.int64), jax.lax.add)
         total = ends[-1]
+    with jax.named_scope("join.expand"):
         slot = jnp.arange(capacity, dtype=jnp.int64)
         pos = jnp.where(matched, jnp.minimum(ends - 1, capacity - 1),
                         capacity)
         probe_idx = jnp.zeros((capacity + 1,),
                               jnp.int64).at[pos].max(iota_p)[:capacity]
-        build_idx = jnp.take(jnp.maximum(brow, 0).astype(jnp.int64),
-                             probe_idx)
+        build_idx = jnp.take(inv, jnp.take(pk0c, probe_idx))
         valid = slot < total
-        return build_idx, probe_idx, valid, total, has_dup
-    cnt = jnp.zeros((domain + 1,), jnp.int32).at[bk0c].add(1)
-    has_dup = cnt[:domain].max() > 1
-    inv = jnp.zeros((domain + 1,), jnp.int64).at[bk0c].set(iota_b)
-    matched = p_live & (cnt[pk0c] > 0)
-    ends = jnp.cumsum(matched.astype(jnp.int64))
-    total = ends[-1]
-    slot = jnp.arange(capacity, dtype=jnp.int64)
-    pos = jnp.where(matched, jnp.minimum(ends - 1, capacity - 1), capacity)
-    probe_idx = jnp.zeros((capacity + 1,), jnp.int64).at[pos].max(iota_p)[:capacity]
-    build_idx = jnp.take(inv, jnp.take(pk0c, probe_idx))
-    valid = slot < total
     return build_idx, probe_idx, valid, total, has_dup
 
 
@@ -502,25 +538,28 @@ def _build_program(spec: FusedSpec, key: str, capacity: int,
             # side remaps its logical key values into the build dictionary
             # (padded with repeats of the last value — searchsorted-left
             # still returns the true first occurrence; see pad_dictionary)
-            bk = bcols[key].astype(jnp.int64)
-            pk_raw = pcols[key]
-            pk_vals = (pk_raw if pdec.get(key) is None
-                       else pdec[key](pk_raw)).astype(jnp.int64)
-            bdict = bdicts[key].astype(jnp.int64)
+            with jax.named_scope("decode"):
+                bk = bcols[key].astype(jnp.int64)
+                pk_raw = pcols[key]
+                pk_vals = (pk_raw if pdec.get(key) is None
+                           else pdec[key](pk_raw)).astype(jnp.int64)
+                bdict = bdicts[key].astype(jnp.int64)
             dbkt = bdict.shape[0]
-            pos = jnp.searchsorted(bdict, pk_vals, side="left")
-            posc = jnp.clip(pos, 0, dbkt - 1)
-            hit = jnp.take(bdict, posc) == pk_vals
-            pk = jnp.where(hit, posc, dense_domain).astype(jnp.int64)
+            with jax.named_scope("join.dict.remap"):
+                pos = jnp.searchsorted(bdict, pk_vals, side="left")
+                posc = jnp.clip(pos, 0, dbkt - 1)
+                hit = jnp.take(bdict, posc) == pk_vals
+                pk = jnp.where(hit, posc, dense_domain).astype(jnp.int64)
         else:
             # join coordinates are int64 (same coercion as tensor_join); the
             # view/output below serves the ORIGINAL key column — dtype and
             # values of result columns never depend on fusion
-            bk_raw, pk_raw = bcols[key], pcols[key]
-            bk = (bk_raw if bdec.get(key) is None
-                  else bdec[key](bk_raw)).astype(jnp.int64)
-            pk = (pk_raw if pdec.get(key) is None
-                  else pdec[key](pk_raw)).astype(jnp.int64)
+            with jax.named_scope("decode"):
+                bk_raw, pk_raw = bcols[key], pcols[key]
+                bk = (bk_raw if bdec.get(key) is None
+                      else bdec[key](bk_raw)).astype(jnp.int64)
+                pk = (pk_raw if pdec.get(key) is None
+                      else pdec[key](pk_raw)).astype(jnp.int64)
         if dense_domain is not None:
             build_idx, probe_idx, valid, total, has_dup = _join_dense(
                 bk, pk, n_build, n_probe, capacity, dense_domain, kmin,
@@ -531,8 +570,9 @@ def _build_program(spec: FusedSpec, key: str, capacity: int,
 
         view = _JoinView(bcols, pcols, key, build_idx, probe_idx, bdec, pdec)
         if spec.filter_fn is not None:
-            mask = jnp.asarray(spec.filter_fn(view), bool)
-            valid = valid & mask
+            with jax.named_scope("filter"):
+                mask = jnp.asarray(spec.filter_fn(view), bool)
+                valid = valid & mask
 
         perm = None
         if spec.sort_keys:
@@ -542,55 +582,59 @@ def _build_program(spec: FusedSpec, key: str, capacity: int,
             # rows sink by pinning their most-significant key to the dtype
             # maximum — their relative position among real max-key rows is
             # irrelevant because only valid rows survive materialization.
-            keys0 = [view[k] for k in spec.sort_keys]
-            msk = keys0[0]
-            if jnp.issubdtype(msk.dtype, jnp.integer):
-                fill = jnp.iinfo(msk.dtype).max
-            else:
-                fill = jnp.inf
-            operands = [jnp.where(valid, msk, fill)] + keys0[1:]
-            operands.append(jnp.arange(capacity, dtype=jnp.int32))
-            sorted_ops = jax.lax.sort(tuple(operands), dimension=0,
-                                      is_stable=True,
-                                      num_keys=len(operands) - 1)
-            perm = sorted_ops[-1]
+            with jax.named_scope("sort"):
+                keys0 = [view[k] for k in spec.sort_keys]
+                msk = keys0[0]
+                if jnp.issubdtype(msk.dtype, jnp.integer):
+                    fill = jnp.iinfo(msk.dtype).max
+                else:
+                    fill = jnp.inf
+                operands = [jnp.where(valid, msk, fill)] + keys0[1:]
+                operands.append(jnp.arange(capacity, dtype=jnp.int32))
+                sorted_ops = jax.lax.sort(tuple(operands), dimension=0,
+                                          is_stable=True,
+                                          num_keys=len(operands) - 1)
+                perm = sorted_ops[-1]
 
         if spec.agg is not None:
             col_name, fn = spec.agg
-            col = view[col_name]
-            v = valid if perm is None else jnp.take(valid, perm)
-            c = col if perm is None else jnp.take(col, perm)
-            # integer columns reduce in int64 (exact, matches the host path
-            # bit-for-bit — f64 would lose integer sums past 2^53)
-            is_int = jnp.issubdtype(c.dtype, jnp.integer)
-            if fn == "sum":
-                zero = jnp.asarray(0, c.dtype)
-                scalar = jnp.where(v, c, zero).sum()
-            elif fn == "count":
-                scalar = v.sum().astype(jnp.int64)
-            elif fn == "min":
-                fill = jnp.iinfo(c.dtype).max if is_int else jnp.inf
-                scalar = jnp.where(v, c, fill).min()
-            elif fn == "max":
-                fill = jnp.iinfo(c.dtype).min if is_int else -jnp.inf
-                scalar = jnp.where(v, c, fill).max()
-            else:
-                raise ValueError(fn)
+            with jax.named_scope("aggregate"):
+                col = view[col_name]
+                v = valid if perm is None else jnp.take(valid, perm)
+                c = col if perm is None else jnp.take(col, perm)
+                # integer columns reduce in int64 (exact, matches the host
+                # path bit-for-bit — f64 would lose integer sums past 2^53)
+                is_int = jnp.issubdtype(c.dtype, jnp.integer)
+                if fn == "sum":
+                    zero = jnp.asarray(0, c.dtype)
+                    scalar = jnp.where(v, c, zero).sum()
+                elif fn == "count":
+                    scalar = v.sum().astype(jnp.int64)
+                elif fn == "min":
+                    fill = jnp.iinfo(c.dtype).max if is_int else jnp.inf
+                    scalar = jnp.where(v, c, fill).min()
+                elif fn == "max":
+                    fill = jnp.iinfo(c.dtype).min if is_int else -jnp.inf
+                    scalar = jnp.where(v, c, fill).max()
+                else:
+                    raise ValueError(fn)
+                agg_n = v.sum()
             # agg_n rides the fetch so the driver can reject min/max over an
             # empty result (the fill value is not a legitimate answer) the
             # way the host path's numpy reduction does
             return {"total": total, "has_dup": has_dup, "scalar": scalar,
-                    "agg_n": v.sum()}
+                    "agg_n": agg_n}
 
         # relation root (sort is the last stage): gather the output schema
         # through the sorted indices — the only payload gathers in the
         # whole pipeline, and they happen once, on device.  A projected
         # root gathers (and later fetches) only its declared subset.
         out_names = view.names() if spec.project is None else spec.project
-        out_cols = {name: (view[name] if perm is None
-                           else jnp.take(view[name], perm))
-                    for name in out_names}
-        out_valid = valid if perm is None else jnp.take(valid, perm)
+        with jax.named_scope("gather"):
+            out_cols = {name: (view[name] if perm is None
+                               else jnp.take(view[name], perm))
+                        for name in out_names}
+            out_valid = valid if perm is None else jnp.take(valid, perm)
         return {"total": total, "has_dup": has_dup, "cols": out_cols,
                 "valid": out_valid}
 
@@ -815,61 +859,70 @@ def run_fused(spec: FusedSpec, build: Relation, probe: Relation,
     with Timer() as t:
         # host planning is part of the query's wall time (the per-op
         # baseline pays for its planning inside its timers too)
-        capacity, dense_domain, kmin = _host_plan(build, probe, spec.join_key)
-        layouts_b, up_b, log_b = get_device_layouts(build, b_bucket)
-        layouts_p, up_p, log_p = get_device_layouts(probe, p_bucket)
-        bcols = {k: dc.codes for k, dc in layouts_b.items()}
-        pcols = {k: dc.codes for k, dc in layouts_p.items()}
-        bdicts = {k: dc.dict_values for k, dc in layouts_b.items()
-                  if dc.dict_values is not None}
-        pdicts = {k: dc.dict_values for k, dc in layouts_p.items()
-                  if dc.dict_values is not None}
-        brefs = {k: dc.layout.ref for k, dc in layouts_b.items()
-                 if dc.encoding == "for"}
-        prefs = {k: dc.layout.ref for k, dc in layouts_p.items()
-                 if dc.encoding == "for"}
-        bsig = tuple(sorted((k, dc.layout.signature())
-                            for k, dc in layouts_b.items()))
-        psig = tuple(sorted((k, dc.layout.signature())
-                            for k, dc in layouts_p.items()))
-        # Dictionary-encoded build key + sampled-unique keys: join in the
-        # code domain — the dense core over the padded dictionary bucket,
-        # even when the VALUE domain is far too wide/sparse for it.  A
-        # wrong uniqueness guess is caught on device (has_dup) and retried
-        # on the sorted value core, same as the value-dense path.
-        key_mode = "value"
-        bkey = layouts_b[spec.join_key]
-        if bkey.encoding == "dict":
-            stats = key_stats(build, spec.join_key)
-            if stats.dup == 1.0 and stats.n:
-                key_mode = "dict"
-                dense_domain = dict_bucket(bkey.layout.card)
-                kmin = 0
+        with tracing.span("rel.host_prep"):
+            capacity, dense_domain, kmin = _host_plan(build, probe,
+                                                      spec.join_key)
+            layouts_b, up_b, log_b = get_device_layouts(build, b_bucket)
+            layouts_p, up_p, log_p = get_device_layouts(probe, p_bucket)
+            bcols = {k: dc.codes for k, dc in layouts_b.items()}
+            pcols = {k: dc.codes for k, dc in layouts_p.items()}
+            bdicts = {k: dc.dict_values for k, dc in layouts_b.items()
+                      if dc.dict_values is not None}
+            pdicts = {k: dc.dict_values for k, dc in layouts_p.items()
+                      if dc.dict_values is not None}
+            brefs = {k: dc.layout.ref for k, dc in layouts_b.items()
+                     if dc.encoding == "for"}
+            prefs = {k: dc.layout.ref for k, dc in layouts_p.items()
+                     if dc.encoding == "for"}
+            bsig = tuple(sorted((k, dc.layout.signature())
+                                for k, dc in layouts_b.items()))
+            psig = tuple(sorted((k, dc.layout.signature())
+                                for k, dc in layouts_p.items()))
+            # Dictionary-encoded build key + sampled-unique keys: join in
+            # the code domain — the dense core over the padded dictionary
+            # bucket, even when the VALUE domain is far too wide/sparse for
+            # it.  A wrong uniqueness guess is caught on device (has_dup)
+            # and retried on the sorted value core, same as the value-dense
+            # path.
+            key_mode = "value"
+            bkey = layouts_b[spec.join_key]
+            if bkey.encoding == "dict":
+                stats = key_stats(build, spec.join_key)
+                if stats.dup == 1.0 and stats.n:
+                    key_mode = "dict"
+                    dense_domain = dict_bucket(bkey.layout.card)
+                    kmin = 0
         while True:
-            use_kernel = (use_pallas(dense_domain)
-                          if dense_domain is not None else False)
-            cache_key = (spec.cache_signature(), capacity, b_bucket,
-                         p_bucket, dense_domain, key_mode, use_kernel,
-                         bsig, psig)
-            prog, fresh = _CACHE.get(
-                cache_key,
-                lambda: _build_program(spec, spec.join_key, capacity,
-                                       dense_domain, key_mode, use_kernel,
-                                       bsig, psig))
+            with tracing.span("rel.host_prep"):
+                use_kernel = (use_pallas(dense_domain)
+                              if dense_domain is not None else False)
+                cache_key = (spec.cache_signature(), capacity, b_bucket,
+                             p_bucket, dense_domain, key_mode, use_kernel,
+                             bsig, psig)
+                prog, fresh = _CACHE.get(
+                    cache_key,
+                    lambda: _build_program(spec, spec.join_key, capacity,
+                                           dense_domain, key_mode,
+                                           use_kernel, bsig, psig))
             # a FRESH program's first call pays multi-second XLA
             # compilation; running it outside the queue keeps one novel
             # shape from stalling every other query's device phase (its
             # own unserialized execution is a one-off, and compiling runs
             # never feed the runtime profile anyway)
             any_fresh = any_fresh or fresh
+            tracing.count(fresh_programs=int(fresh), dispatches=1)
             lease = None
             if not fresh:
                 lease = broker.device_lease(batch_key=("fused", cache_key))
                 queue_wait += lease.wait_s
             try:
-                out = prog(bcols, pcols, bdicts, pdicts, brefs, prefs,
-                           n_build, n_probe, kmin)
-                fetched = jax.device_get(out)  # THE host sync of the query
+                with tracing.span("rel.compile" if fresh
+                                  else "rel.dispatch"):
+                    out = prog(bcols, pcols, bdicts, pdicts, brefs, prefs,
+                               n_build, n_probe, kmin)
+                with tracing.span("rel.fetch"):
+                    # THE host sync of the query
+                    fetched = jax.device_get(out)
             finally:
                 if lease is not None:
                     lease.release()
@@ -888,6 +941,7 @@ def run_fused(spec: FusedSpec, build: Relation, probe: Relation,
                 dense_domain = None
                 key_mode = "value"
                 kmin = 0
+                tracing.count(retries=1)
                 continue
             if total <= capacity:
                 break
@@ -897,17 +951,20 @@ def run_fused(spec: FusedSpec, build: Relation, probe: Relation,
                 # the retry dispatch (raises SwitchPoint to abandon)
                 guard.observe_fragment(total, capacity)
             capacity = capacity_bucket(total)  # rare: bucket overflowed
-        if spec.agg is not None:
-            if spec.agg[1] in ("min", "max") and int(fetched["agg_n"]) == 0:
-                raise ValueError(
-                    f"{spec.agg[1]} over an empty result has no identity")
-            result = float(fetched["scalar"])
-            rows_out = 1
-        else:
-            keep = np.nonzero(np.asarray(fetched["valid"]))[0]
-            result = Relation({k: np.asarray(v)[keep]
-                               for k, v in fetched["cols"].items()})
-            rows_out = len(result)
+            tracing.count(retries=1)
+        with tracing.span("rel.assemble"):
+            if spec.agg is not None:
+                if (spec.agg[1] in ("min", "max")
+                        and int(fetched["agg_n"]) == 0):
+                    raise ValueError(f"{spec.agg[1]} over an empty result "
+                                     f"has no identity")
+                result = float(fetched["scalar"])
+                rows_out = 1
+            else:
+                keep = np.nonzero(np.asarray(fetched["valid"]))[0]
+                result = Relation({k: np.asarray(v)[keep]
+                                   for k, v in fetched["cols"].items()})
+                rows_out = len(result)
     metrics = OpMetrics(
         op="fused_pipeline",
         path="tensor",
@@ -959,40 +1016,45 @@ def _run_fused_sharded(spec: FusedSpec, build: Relation, probe: Relation,
     batched = False
     broker.ensure_lanes(num_parts)
     with Timer() as t:
-        stats = key_stats(build, spec.join_key)
-        (bcols, counts_b_dev, counts_b, bucket_b, up_b, log_b, b_lay,
-         bdicts) = get_partitioned_columns(build, spec.join_key, num_parts,
-                                           sort_within=True)
-        (pcols, counts_p_dev, counts_p, bucket_p, up_p, log_p, p_lay,
-         pdicts) = get_partitioned_columns(probe, spec.join_key, num_parts,
-                                           sort_within=False)
-        brefs = {k: lay.ref for k, lay in b_lay.items()
-                 if lay.encoding == "for"}
-        prefs = {k: lay.ref for k, lay in p_lay.items()
-                 if lay.encoding == "for"}
-        bsig = tuple(sorted((k, lay.signature()) for k, lay in b_lay.items()))
-        psig = tuple(sorted((k, lay.signature()) for k, lay in p_lay.items()))
-        est_part_out = int(max(1, int(counts_p.max())) * stats.dup)
-        capacity = partition_bucket(int(est_part_out * 1.25))
-        # A verified-capacity hint from an earlier run of this fragment over
-        # the same data: the optimistic estimate is recomputed per call, so
-        # without the hint a query whose critical partition overflows it
-        # would pay the overflow retry (a second dispatch + fetch) on EVERY
-        # warm serving query, not just the first.
-        hint_key = (spec.cache_signature(), num_parts,
-                    column_token(build[spec.join_key]),
-                    column_token(probe[spec.join_key]))
-        with _CAP_HINT_LOCK:
-            capacity = max(capacity, _CAP_HINTS.get(hint_key, 0))
+        with tracing.span("rel.host_prep"):
+            stats = key_stats(build, spec.join_key)
+            (bcols, counts_b_dev, counts_b, bucket_b, up_b, log_b, b_lay,
+             bdicts) = get_partitioned_columns(build, spec.join_key,
+                                               num_parts, sort_within=True)
+            (pcols, counts_p_dev, counts_p, bucket_p, up_p, log_p, p_lay,
+             pdicts) = get_partitioned_columns(probe, spec.join_key,
+                                               num_parts, sort_within=False)
+            brefs = {k: lay.ref for k, lay in b_lay.items()
+                     if lay.encoding == "for"}
+            prefs = {k: lay.ref for k, lay in p_lay.items()
+                     if lay.encoding == "for"}
+            bsig = tuple(sorted((k, lay.signature())
+                                for k, lay in b_lay.items()))
+            psig = tuple(sorted((k, lay.signature())
+                                for k, lay in p_lay.items()))
+            est_part_out = int(max(1, int(counts_p.max())) * stats.dup)
+            capacity = partition_bucket(int(est_part_out * 1.25))
+            # A verified-capacity hint from an earlier run of this fragment
+            # over the same data: the optimistic estimate is recomputed per
+            # call, so without the hint a query whose critical partition
+            # overflows it would pay the overflow retry (a second dispatch
+            # + fetch) on EVERY warm serving query, not just the first.
+            hint_key = (spec.cache_signature(), num_parts,
+                        column_token(build[spec.join_key]),
+                        column_token(probe[spec.join_key]))
+            with _CAP_HINT_LOCK:
+                capacity = max(capacity, _CAP_HINTS.get(hint_key, 0))
         while True:
-            cache_key = ("sharded", spec.cache_signature(), num_parts,
-                         capacity, bucket_b, bucket_p, bsig, psig)
-            prog, fresh = _CACHE.get(
-                cache_key,
-                lambda: _build_sharded_program(spec, spec.join_key,
-                                               num_parts, capacity,
-                                               bsig, psig))
+            with tracing.span("rel.host_prep"):
+                cache_key = ("sharded", spec.cache_signature(), num_parts,
+                             capacity, bucket_b, bucket_p, bsig, psig)
+                prog, fresh = _CACHE.get(
+                    cache_key,
+                    lambda: _build_sharded_program(spec, spec.join_key,
+                                                   num_parts, capacity,
+                                                   bsig, psig))
             any_fresh = any_fresh or fresh
+            tracing.count(fresh_programs=int(fresh), dispatches=1)
             # ALWAYS under the gang lease — including the compile dispatch.
             # A sharded launch runs collectives over every lane's device;
             # any unleased dispatch (the old fresh-path bypass) can overlap
@@ -1001,9 +1063,13 @@ def _run_fused_sharded(spec: FusedSpec, build: Relation, probe: Relation,
             lease = broker.device_lease(lanes=num_parts)
             queue_wait += lease.wait_s
             try:
-                out = prog(bcols, pcols, bdicts, pdicts, brefs, prefs,
-                           counts_b_dev, counts_p_dev)
-                fetched = jax.device_get(out)  # THE host sync of the query
+                with tracing.span("rel.compile" if fresh
+                                  else "rel.dispatch"):
+                    out = prog(bcols, pcols, bdicts, pdicts, brefs, prefs,
+                               counts_b_dev, counts_p_dev)
+                with tracing.span("rel.fetch"):
+                    # THE host sync of the query
+                    fetched = jax.device_get(out)
             finally:
                 lease.release()
                 batched = batched or lease.batched
@@ -1022,10 +1088,12 @@ def _run_fused_sharded(spec: FusedSpec, build: Relation, probe: Relation,
                         partition_bucket(max_part))
                 break
             capacity = partition_bucket(max_part)  # rare: skewed overflow
-        if spec.agg[1] in ("min", "max") and int(fetched["agg_n"]) == 0:
-            raise ValueError(
-                f"{spec.agg[1]} over an empty result has no identity")
-        result = float(fetched["scalar"])
+            tracing.count(retries=1)
+        with tracing.span("rel.assemble"):
+            if spec.agg[1] in ("min", "max") and int(fetched["agg_n"]) == 0:
+                raise ValueError(
+                    f"{spec.agg[1]} over an empty result has no identity")
+            result = float(fetched["scalar"])
     metrics = OpMetrics(
         op="fused_pipeline",
         path="tensor",

@@ -164,11 +164,6 @@ class OpMetrics:
     # also counted in spill.bytes_read, so books stay balanced).
     reused_spill_bytes: int = 0
 
-    @property
-    def h2d_bytes_physical(self) -> int:
-        """Alias for :attr:`h2d_bytes` — the bytes that really moved."""
-        return self.h2d_bytes
-
     def as_row(self) -> Dict[str, object]:
         return {
             "op": self.op,

@@ -51,6 +51,7 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from . import tracing
 from .codec_device import (DeviceColumnLayout, compress_enabled, dict_bucket,
                            encode_host, pad_dictionary)
 from .relation import Relation, column_token
@@ -261,8 +262,9 @@ def get_partitioned_columns(rel: Relation, key: str, num_parts: int,
         _COUNTERS.misses += 1
     host_cols, counts, bucket, layouts, dicts_host = _build_partitions(
         rel, key, num_parts, sort_within)
-    cols, counts_dev, dicts_dev = _upload(host_cols, counts, num_parts,
-                                          dicts_host)
+    with tracing.span("rel.h2d"):
+        cols, counts_dev, dicts_dev = _upload(host_cols, counts, num_parts,
+                                              dicts_host)
     uploaded = sum(int(b.nbytes) for b in host_cols.values()) + counts.nbytes
     uploaded += sum(int(d.nbytes) for d in dicts_host.values())
     logical = int(num_parts * bucket

@@ -44,6 +44,7 @@ from typing import Dict, FrozenSet, List, Optional, Tuple
 
 import numpy as np
 
+from . import tracing
 from .expr import CombinedPredicate, Expr
 from .logical import (LAggregate, LFilter, LGroupBy, LJoin, LProject, LScan,
                       LSort, LogicalNode, from_physical, is_scalar,
@@ -514,11 +515,12 @@ def plan_program(plan, rewrite: bool = True) -> Program:
     are structural lowering and always apply."""
     from .executor import PHYSICAL_NODES
 
-    if isinstance(plan, PHYSICAL_NODES):
-        plan = from_physical(plan)
-    if rewrite:
-        plan = push_filters(plan)
-        plan = prune_columns(plan)
-    stages: List[Stage] = []
-    _compile_stage(plan, stages)
-    return Program(stages, scalar=is_scalar(plan))
+    with tracing.span("rel.plan"):
+        if isinstance(plan, PHYSICAL_NODES):
+            plan = from_physical(plan)
+        if rewrite:
+            plan = push_filters(plan)
+            plan = prune_columns(plan)
+        stages: List[Stage] = []
+        _compile_stage(plan, stages)
+        return Program(stages, scalar=is_scalar(plan))

@@ -65,6 +65,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from . import tracing
 from .faults import FaultInjector, PreemptedError
 from .memory_governor import MemoryGovernor, MemoryGrant, MemoryHold
 
@@ -729,26 +730,27 @@ class ResourceBroker:
         """
         if self.faults is not None:
             self.faults.on_device_dispatch()
-        if lanes <= 1:
-            return self.device.acquire(batch_key)
-        self.ensure_lanes(lanes)
-        with self._lock:
-            queues = list(self._lanes[:lanes])
-        # Gangs never coalesce: a sharded launch runs cross-device
-        # collectives, and two gangs admitted as one batch_key group would
-        # interleave collective launches — on the host platform that is a
-        # rendezvous deadlock, not a slowdown.  Strict per-lane exclusion in
-        # fixed lane order serializes gangs against each other and against
-        # single-lane (lane 0) dispatch.
-        held: List[DeviceLease] = []
-        try:
-            for q in queues:
-                held.append(q.acquire(None))
-        except BaseException:
-            for lease in reversed(held):
-                lease.release()
-            raise
-        return DeviceGangLease(held)
+        with tracing.span("rel.lease_wait"):
+            if lanes <= 1:
+                return self.device.acquire(batch_key)
+            self.ensure_lanes(lanes)
+            with self._lock:
+                queues = list(self._lanes[:lanes])
+            # Gangs never coalesce: a sharded launch runs cross-device
+            # collectives, and two gangs admitted as one batch_key group
+            # would interleave collective launches — on the host platform
+            # that is a rendezvous deadlock, not a slowdown.  Strict
+            # per-lane exclusion in fixed lane order serializes gangs
+            # against each other and against single-lane (lane 0) dispatch.
+            held: List[DeviceLease] = []
+            try:
+                for q in queues:
+                    held.append(q.acquire(None))
+            except BaseException:
+                for lease in reversed(held):
+                    lease.release()
+                raise
+            return DeviceGangLease(held)
 
     # -- reservations --------------------------------------------------------
     def reserve(self, request: ResourceRequest) -> Reservation:

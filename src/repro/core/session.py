@@ -37,6 +37,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Sequence, Union
 
+from . import tracing
 from .executor import Executor, QueryResult
 from .expr import Expr
 from .logical import (LAggregate, LFilter, LGroupBy, LJoin, LProject, LScan,
@@ -148,7 +149,10 @@ class Session:
         from .planner import plan_program
 
         node = plan.logical() if isinstance(plan, Query) else plan
-        return plan_program(node, rewrite=rewrite).run(self.executor)
+        with tracing.query() as trace:
+            res = plan_program(node, rewrite=rewrite).run(self.executor)
+        res.trace = trace
+        return res
 
 
 class Query:

@@ -43,6 +43,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from . import tracing
 from .codec_device import (DeviceCodes, DeviceColumnLayout, choose_layout,
                            compress_enabled, dict_bucket, encode_host,
                            pad_dictionary)
@@ -140,9 +141,10 @@ def get_device_columns(rel: Relation, bucket: Optional[int] = None
     uploaded = 0
     out: Dict[str, object] = {}
     if not cache_enabled():
-        for name, col in rel.columns.items():
-            out[name] = _upload(col, bucket)
-            uploaded += _padded_nbytes(col, bucket)
+        with tracing.span("rel.h2d"):
+            for name, col in rel.columns.items():
+                out[name] = _upload(col, bucket)
+                uploaded += _padded_nbytes(col, bucket)
         with _LOCK:
             _COUNTERS.misses += len(rel.columns)
             _COUNTERS.h2d_bytes += uploaded
@@ -166,11 +168,12 @@ def get_device_columns(rel: Relation, bucket: Optional[int] = None
     # it.  Two queries racing on the same cold column both transfer (the
     # bytes they report were really moved); the last insert wins and all
     # later queries are warm.
-    for name in missing:
-        col = rel.columns[name]
-        out[name] = _upload(col, bucket)
-        uploaded += _padded_nbytes(col, bucket)
     if missing:
+        with tracing.span("rel.h2d"):
+            for name in missing:
+                col = rel.columns[name]
+                out[name] = _upload(col, bucket)
+                uploaded += _padded_nbytes(col, bucket)
         with _LOCK:
             for name in missing:
                 cache[(name, bucket)] = (tokens[name], out[name])
@@ -243,12 +246,13 @@ def get_device_layouts(rel: Relation, bucket: Optional[int] = None
         return out, up_phys, up_log
     tokens = {name: column_token(rel.columns[name]) for name in packed}
     if not cache_enabled():
-        for name in packed:
-            dc, phys = _upload_packed(rel.columns[name], *layouts[name],
-                                      bucket)
-            out[name] = dc
-            up_phys += phys
-            up_log += _padded_nbytes(rel.columns[name], bucket)
+        with tracing.span("rel.h2d"):
+            for name in packed:
+                dc, phys = _upload_packed(rel.columns[name], *layouts[name],
+                                          bucket)
+                out[name] = dc
+                up_phys += phys
+                up_log += _padded_nbytes(rel.columns[name], bucket)
         with _LOCK:
             _COUNTERS.misses += len(packed)
             _COUNTERS.h2d_bytes += up_phys
@@ -281,12 +285,14 @@ def get_device_layouts(rel: Relation, bucket: Optional[int] = None
     # encodes + transfers outside the lock (same double-checked-insert
     # discipline as get_device_columns)
     fresh_phys = fresh_log = 0
-    for name in missing:
-        dc, phys = _upload_packed(rel.columns[name], *layouts[name], bucket)
-        out[name] = dc
-        fresh_phys += phys
-        fresh_log += _padded_nbytes(rel.columns[name], bucket)
     if missing:
+        with tracing.span("rel.h2d"):
+            for name in missing:
+                dc, phys = _upload_packed(rel.columns[name], *layouts[name],
+                                          bucket)
+                out[name] = dc
+                fresh_phys += phys
+                fresh_log += _padded_nbytes(rel.columns[name], bucket)
         with _LOCK:
             for name in missing:
                 cache[(name, bucket, "c")] = (tokens[name], out[name])

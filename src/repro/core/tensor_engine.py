@@ -215,6 +215,7 @@ def radix_hash_probe_dispatch(bk_codes, pk_codes, domain: int,
 # Join: sorted coordinate alignment
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("op.join")
 def _join_plan_impl(build_keys, probe_keys):
     """Shared device planning stage: ONE sort + searchsorted produces both the
     exact match count (the capacity signal) and the alignment arrays the join
@@ -236,6 +237,7 @@ def _join_plan_impl(build_keys, probe_keys):
 _join_plan = jax.jit(_join_plan_impl)
 
 
+@jax.named_scope("op.join")
 def _expand_join_impl(order, left, starts, ends, capacity: int):
     n_build = order.shape[0]
     n_probe = ends.shape[0]
@@ -434,6 +436,7 @@ _AGG_DTYPE = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
 
 @partial(jax.jit, static_argnames=("num_segments", "use_kernel"))
+@jax.named_scope("op.join_aggregate")
 def _join_aggregate(
     build_keys, build_vals, probe_keys, probe_vals, num_segments: int,
     use_kernel: Tuple[bool, bool, bool, bool] = (False,) * 4
@@ -521,6 +524,7 @@ def tensor_join_aggregate(
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("num_keys", "has_valid"))
+@jax.named_scope("op.sort")
 def _multikey_perm(key_cols: Tuple[jnp.ndarray, ...], valid, num_keys: int,
                    has_valid: bool = False) -> jnp.ndarray:
     n = key_cols[0].shape[0]

@@ -97,3 +97,25 @@ def test_segment_sum_rule_exact_at_scale(monkeypatch, max_abs, takes_kernel):
         rule)
     want = np.bincount(seg, weights=vals, minlength=2048)
     np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_fused_join_core_keeps_its_scopes_on_the_tpu(one_chip):
+    """The TPU compiler keeps the join core's ``jax.named_scope`` names in
+    the compiled program's ``op_name`` metadata, the prefix sum and the
+    running max included (they are written as reduce-windows in scope)."""
+    from repro.core import fused
+
+    spec = fused.FusedSpec("k", None, (), ("b_v", "sum"))
+    prog = fused._build_program(spec, "k", 1 << 14)
+
+    def shape(n):
+        return jax.ShapeDtypeStruct((n,), jnp.int64, sharding=one_chip)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    text = prog.lower({"k": shape(1 << 12), "v": shape(1 << 12)},
+                      {"k": shape(1 << 14)}, {}, {}, {}, {}, scalar, scalar,
+                      scalar).compile().as_text()
+    for path in ("join.sorted.sort/", "join.sorted.search/",
+                 "join.prefix_sum/reduce_window_sum",
+                 "join.expand/reduce_window_max"):
+        assert f'op_name="jit(program)/{path}' in text, path
