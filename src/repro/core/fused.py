@@ -27,6 +27,7 @@ numpy inputs and costs no device traffic.
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 from typing import Callable, Dict, Optional, Tuple
 
@@ -354,8 +355,62 @@ def _prefix(x, reducer):
                                  (1,), [(n - 1, 0)])
 
 
+# Merged-sort work per binary-search step at which the two formulations of
+# :func:`_align_sorted` cost the same, fitted on a TPU v5e at a 2^20-row
+# build: the pair of searches, ~35 ns a probe and step there, against the
+# merged sort, 7.2 ms over 2^20 + 2^12..2^17 rows.  The searches then win
+# below about 10,000 probes.
+_SORT_WORK_PER_SEARCH_STEP = 2000
+
+
+def _merge_beats_search(B: int, P: int) -> bool:
+    """Static choice of :func:`_align_sorted`'s formulation from the bucket
+    sizes: ``P·⌈log2(B+1)⌉`` search steps against ``(B+P)·log2(B+P)²`` of
+    merged sort work.  Few probes against a large build keep the search."""
+    if B == 0 or P == 0:
+        return False
+    n = B + P
+    search = P * B.bit_length()  # B.bit_length() == ⌈log2(B+1)⌉
+    return n * math.log2(n) ** 2 < _SORT_WORK_PER_SEARCH_STEP * search
+
+
+def _align_sorted(sk, pk):
+    """``searchsorted(sk, pk, "left")`` and ``searchsorted(sk, pk,
+    "right")`` over the ascending build keys ``sk``, equal in value and
+    dtype, from one merged sort when the shapes make that cheaper.
+
+    The binary search is a ``while`` loop of random gathers, which the TPU
+    does slowly; it sorts quickly.  The merged form sorts the build and
+    probe keys together, build rows first among equal keys, so a probe's
+    ``right`` is the running count of build rows before it and its
+    ``left`` that count at the start of its run of equal keys, forward
+    filled by a running max (the count never decreases).  A second sort,
+    by probe row, puts both back in probe order.  No gather, no scatter.
+    """
+    B, P = sk.shape[0], pk.shape[0]
+    if not _merge_beats_search(B, P):
+        return (jnp.searchsorted(sk, pk, side="left"),
+                jnp.searchsorted(sk, pk, side="right"))
+    i32 = np.iinfo(np.int32).max
+    out = np.int32 if B <= i32 else np.int64  # searchsorted's dtype
+    row_t = np.int32 if B + P <= i32 else np.int64
+    keys = jnp.concatenate([sk, pk])
+    # build rows carry row -1, so each sorts before the probes of its key
+    row = jnp.concatenate([jnp.full((B,), -1, row_t),
+                           jnp.arange(P, dtype=row_t)])
+    keys, row = jax.lax.sort((keys, row), num_keys=2)
+    is_build = (row < 0).astype(out)
+    right = _prefix(is_build, jax.lax.add)
+    run_start = jnp.concatenate([jnp.ones((1,), bool), keys[1:] != keys[:-1]])
+    left = _prefix(jnp.where(run_start, right - is_build, 0), jax.lax.max)
+    # the B build rows (row -1) sort first; the probes follow in row order
+    _, left, right = jax.lax.sort((row, left, right), num_keys=1)
+    return left[B:], right[B:]
+
+
 def _join_sorted(bk, pk, n_build, n_probe, capacity):
-    """General join core: sorted coordinate alignment (one device sort)."""
+    """General join core: sorted coordinate alignment (a device sort of
+    the build side, then :func:`_align_sorted`)."""
     B = bk.shape[0]
     P = pk.shape[0]
     iota_b = jnp.arange(B)
@@ -366,8 +421,7 @@ def _join_sorted(bk, pk, n_build, n_probe, capacity):
         order = jnp.argsort(bk_m, stable=True)
         sk = jnp.take(bk_m, order)
     with jax.named_scope("join.sorted.search"):
-        left = jnp.searchsorted(sk, pk, side="left")
-        right = jnp.searchsorted(sk, pk, side="right")
+        left, right = _align_sorted(sk, pk)
         counts = right - left
         # padded probe rows contribute nothing; a real probe key equal to
         # the int64 sentinel would false-match padded build rows, so it is
@@ -397,9 +451,9 @@ def _join_sorted_run(sk, pk, n_probe, capacity):
     """Join core over a PRE-SORTED build run (the sharded path).
 
     The partitioned layout (:mod:`repro.core.partition`) stores each build
-    partition key-sorted with sentinel padding at the tail, so alignment is
-    a searchsorted probe over an already-ordered, cache-resident run —
-    **no per-query device sort at all**.  ``build_idx`` therefore indexes
+    partition key-sorted with sentinel padding at the tail, so alignment
+    (:func:`_align_sorted`) runs over an already-ordered, cache-resident
+    run — **no per-query build sort**.  ``build_idx`` therefore indexes
     the stored run directly (the single-device core needs an ``order``
     indirection because it sorts inside the program).  Expansion is the
     same scatter + running-max forward fill as :func:`_join_sorted`.
@@ -408,8 +462,7 @@ def _join_sorted_run(sk, pk, n_probe, capacity):
     P = pk.shape[0]
     iota_p = jnp.arange(P)
     with jax.named_scope("join.sorted.search"):
-        left = jnp.searchsorted(sk, pk, side="left")
-        right = jnp.searchsorted(sk, pk, side="right")
+        left, right = _align_sorted(sk, pk)
         # sentinel-padded probe rows contribute nothing (same key-domain
         # contract as the single-device core)
         counts = jnp.where((iota_p < n_probe) & (pk != _I64_MAX),

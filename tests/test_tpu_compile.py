@@ -99,23 +99,44 @@ def test_segment_sum_rule_exact_at_scale(monkeypatch, max_abs, takes_kernel):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
-def test_fused_join_core_keeps_its_scopes_on_the_tpu(one_chip):
-    """The TPU compiler keeps the join core's ``jax.named_scope`` names in
-    the compiled program's ``op_name`` metadata, the prefix sum and the
-    running max included (they are written as reduce-windows in scope)."""
+def _sorted_program_text(one_chip, build_rows, probe_rows):
     from repro.core import fused
 
     spec = fused.FusedSpec("k", None, (), ("b_v", "sum"))
-    prog = fused._build_program(spec, "k", 1 << 14)
+    prog = fused._build_program(spec, "k", probe_rows)
 
     def shape(n):
         return jax.ShapeDtypeStruct((n,), jnp.int64, sharding=one_chip)
 
     scalar = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
-    text = prog.lower({"k": shape(1 << 12), "v": shape(1 << 12)},
-                      {"k": shape(1 << 14)}, {}, {}, {}, {}, scalar, scalar,
-                      scalar).compile().as_text()
+    return prog.lower({"k": shape(build_rows), "v": shape(build_rows)},
+                      {"k": shape(probe_rows)}, {}, {}, {}, {}, scalar,
+                      scalar, scalar).compile().as_text()
+
+
+def test_fused_join_core_keeps_its_scopes_on_the_tpu(one_chip):
+    """The TPU compiler keeps the join core's ``jax.named_scope`` names in
+    the compiled program's ``op_name`` metadata, the prefix sum and the
+    running max included (they are written as reduce-windows in scope)."""
+    text = _sorted_program_text(one_chip, 1 << 12, 1 << 14)
     for path in ("join.sorted.sort/", "join.sorted.search/",
                  "join.prefix_sum/reduce_window_sum",
                  "join.expand/reduce_window_max"):
         assert f'op_name="jit(program)/{path}' in text, path
+
+
+@pytest.mark.parametrize("build_rows,probe_rows,merged", [
+    (1 << 20, ROWS, True),      # TPC-H Q9's partsupp and lineitem buckets
+    (1 << 14, 1 << 6, False),   # few probes: the binary search is cheaper
+])
+def test_sorted_join_aligns_by_sort_where_probes_are_many(
+        one_chip, build_rows, probe_rows, merged):
+    """At the SF1 lineitem ⋈ partsupp buckets the sorted core aligns by
+    one merged sort: ``join.sorted.search`` holds sorts and no ``while``
+    (the binary search's loop); a small probe side keeps the search."""
+    text = _sorted_program_text(one_chip, build_rows, probe_rows)
+    scope = 'op_name="jit(program)/join.sorted.search/'
+    ops = [ln for ln in text.splitlines() if scope in ln]
+    assert ops
+    assert any(" sort(" in ln for ln in ops) is merged
+    assert any(" while(" in ln for ln in ops) is not merged
