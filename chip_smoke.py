@@ -12,8 +12,10 @@ Everything runs in this one process, through the user entry points
   factor 1: ``orders`` has 6,001,215 rows (SSB lineorder), ``users``
   30,000 (SSB customer: the dense jnp join core) and ``parts`` 2,000 (SSB
   supplier: a code domain the Pallas radix probe takes).  The fig10 query
-  must run as chained ``fused_pipeline`` fragments and its
-  ``group_by("pid")`` variant on the tensor path, with no linear operator.
+  must run as chained ``fused_pipeline`` fragments, and a revenue sum and
+  its ``group_by(("b_region", "pid"))`` variant, which read the parts'
+  price through the radix-probe join, on the tensor path, with no linear
+  operator.
 * **B, sparse-key join** — the fig15 shape with 2^23 rows per side and
   sparse int64 keys: the sorted int64 join core.
 * **C, serving** — a governed ``QueryServer`` over the Phase A tables under
@@ -114,14 +116,19 @@ def star_oracle(tables):
     import numpy as np
 
     o = tables["orders"]
-    region = np.asarray(tables["users"]["region"])
-    mask = (o["w"] > 0) & (region[o["uid"]] <= 2)
-    w, pid = o["w"][mask], o["pid"][mask]
-    groups = np.unique(pid)
-    sums = np.bincount(pid, weights=w.astype(np.float64),
-                       minlength=SF1_PARTS)[groups]
+    region = np.asarray(tables["users"]["region"])[o["uid"]]
+    price = np.asarray(tables["parts"]["price"])[o["pid"]]
+    mask = (o["w"] > 0) & (region <= 2)
+    w, pid, wp = o["w"][mask], o["pid"][mask], (o["w"] * price)[mask]
+    # (region, pid) packed as region * parts + pid, in lexicographic order
+    coord = region[mask] * SF1_PARTS + pid
+    groups = np.unique(coord)
+    sums = np.bincount(coord, weights=wp.astype(np.float64),
+                       minlength=4 * SF1_PARTS)[groups]
     return {"sum": float(w.sum()), "count": float(mask.sum()),
-            "group_pid": groups, "group_sum": sums}
+            "revenue": float(wp.sum()),
+            "group_region": groups // SF1_PARTS,
+            "group_pid": groups % SF1_PARTS, "group_sum": sums}
 
 
 def star_queries(sess):
@@ -131,9 +138,13 @@ def star_queries(sess):
             .join(sess.table("users"), on="uid")
             .join(sess.table("parts"), on="pid")
             .filter((col("w") > 0) & (col("b_region") <= 2)))
+    # revenue and the group's measure read the parts' price, a build-side
+    # column of the join the Pallas radix probe takes on the chip
+    revenue = ("wp", col("w") * col("b_price"))
     return {"sum": star.sort("uid").aggregate("w", "sum"),
             "count": star.aggregate("w", "count"),
-            "group": star.group_by("pid", {"w": "sum"})}
+            "revenue": star.aggregate(revenue, "sum"),
+            "group": star.group_by(("b_region", "pid"), {revenue: "sum"})}
 
 
 def sparse_tables(rng, scale: float):
@@ -199,7 +210,7 @@ def phase_a(star, oracle) -> dict:
     qs = star_queries(sess)
 
     def run():
-        return {k: qs[k].collect() for k in ("sum", "group")}
+        return {k: qs[k].collect() for k in ("sum", "revenue", "group")}
 
     res, cold = _timed(run)
     _, warm = _timed(run)
@@ -209,12 +220,18 @@ def phase_a(star, oracle) -> dict:
     frags = [m.op for m in s.metrics].count("fused_pipeline")
     check(frags >= 2, f"A: star join ran {frags} fused fragments, not >= 2")
     _device_only(s, "A star join")
+    r = res["revenue"]
+    check(r.scalar == oracle["revenue"],
+          f"A: star-join revenue {r.scalar!r} != oracle "
+          f"{oracle['revenue']!r}")
+    _device_only(r, "A revenue")
     g = res["group"]
     rel = g.relation
-    order = np.argsort(rel["pid"])
-    check(np.array_equal(rel["pid"][order], oracle["group_pid"])
-          and np.array_equal(rel["sum_w"][order], oracle["group_sum"]),
-          "A: group_by(pid) sums differ from the oracle")
+    order = np.lexsort((rel["pid"], rel["b_region"]))
+    check(np.array_equal(rel["b_region"][order], oracle["group_region"])
+          and np.array_equal(rel["pid"][order], oracle["group_pid"])
+          and np.array_equal(rel["sum_wp"][order], oracle["group_sum"]),
+          "A: group_by(b_region, pid) sums differ from the oracle")
     check(any(m.op == "group_aggregate" and m.path == "tensor"
               for m in g.metrics), "A: the group-by left the tensor path")
     _device_only(g, "A group-by")

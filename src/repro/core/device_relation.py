@@ -185,10 +185,12 @@ class DeviceRelation:
     def to_host(self) -> Relation:
         """Materialize to a host Relation with ONE batched device→host fetch."""
         forced = {k: c.force() for k, c in self.columns.items()}
+        # device_get rebuilds the dict with sorted keys: the result keeps
+        # this relation's column order instead
         if self.valid is not None:
             payload = jax.device_get((forced, self.valid))
             cols, valid = payload
             keep = np.nonzero(np.asarray(valid))[0]
-            return Relation({k: np.asarray(v)[keep] for k, v in cols.items()})
+            return Relation({k: np.asarray(cols[k])[keep] for k in forced})
         cols = jax.device_get(forced)
-        return Relation({k: np.asarray(v) for k, v in cols.items()})
+        return Relation({k: np.asarray(cols[k]) for k in forced})

@@ -26,7 +26,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -93,18 +93,30 @@ class Sort:
 
 @dataclasses.dataclass
 class Aggregate:
+    """Scalar reduction of a stored column, or of a computed ``measure``
+    (an :class:`~repro.core.expr.Expr` over the child's columns, evaluated
+    row-wise before the reduction; ``column`` is then its name)."""
     child: object
     column: str
     fn: str = "sum"  # sum | count | min | max
     name: str = "aggregate"
+    measure: Optional[object] = None
 
 
 @dataclasses.dataclass
 class GroupBy:
+    """GROUP BY ``key``: one column name or a tuple of them.  ``values``
+    maps a stored column or a name of ``measures`` to its aggregate.
+    Several keys group by their lexicographic order."""
     child: object
-    key: str
-    values: dict  # column -> agg fn
+    key: Union[str, Tuple[str, ...]]
+    values: dict  # column or measure name -> agg fn
     name: str = "group_by"
+    measures: dict = dataclasses.field(default_factory=dict)
+
+    @property
+    def keys(self) -> Tuple[str, ...]:
+        return (self.key,) if isinstance(self.key, str) else tuple(self.key)
 
 
 @dataclasses.dataclass
@@ -1141,6 +1153,7 @@ class Executor:
         if isinstance(node, GroupBy):
             child = self._exec(node.child, metrics, decisions, mgr)
             from .aggregate import group_aggregate_device, group_aggregate_linear
+            keys = node.keys
             # GROUP BY is the third linearizing operator: the group hash
             # table is the linearized intermediate; selection mirrors sort
             # the probe uses the same unit estimate_sort's fits-check
@@ -1153,15 +1166,19 @@ class Executor:
             try:
                 with tracing.span("rel.select"):
                     decision = self._decide(self.selector.choose_sort(
-                        child, [node.key], mem_quote=mem_q, dev_quote=dev_q))
+                        child, list(keys), mem_quote=mem_q, dev_quote=dev_q))
                 decisions.append(decision)
                 if decision.path == "tensor":
                     dev_c, up_c, log_c = self._to_device(child)
-                    sig = ("group", dev_c.num_physical_rows,
-                           tuple(node.values.items()), dev_c.valid is None)
+                    sig = ("group", dev_c.num_physical_rows, keys,
+                           tuple(node.values.items()),
+                           tuple((n, e.cache_token())
+                                 for n, e in node.measures.items()),
+                           dev_c.valid is None)
                     with self._device_leased(sig) as lease:
-                        out, m = group_aggregate_device(dev_c, node.key,
-                                                        node.values)
+                        out, m = group_aggregate_device(
+                            dev_c, keys, node.values,
+                            measures=node.measures)
                     self._stamp_lease(m, lease)
                     m.h2d_bytes += up_c
                     m.h2d_bytes_logical += log_c
@@ -1169,19 +1186,23 @@ class Executor:
                     child, syncs = self._lower_for_linear(child)
                     # grant sized by estimated DISTINCT groups (the group
                     # hash table's real footprint), via the cached key
-                    # sketch — a low-cardinality aggregate over many rows
+                    # sketches — a low-cardinality aggregate over many rows
                     # must not hold a work_mem-sized slice of the shared
                     # budget it cannot use
                     from .table_cache import key_stats
 
-                    st = key_stats(child, node.key)
-                    scale = max(1, len(child) // max(1, st.sample_n))
-                    n_groups = min(len(child), max(1, st.card * scale))
+                    n_groups = 1
+                    for k in keys:
+                        st = key_stats(child, k)
+                        scale = max(1, len(child) // max(1, st.sample_n))
+                        n_groups *= max(1, st.card * scale)
+                    n_groups = min(len(child), n_groups)
                     with self._granted(self.selector.model.hash_need_bytes(
                             n_groups), reservation=rsv) as (wm, grant):
                         self._apply_tier_quota(mgr, grant)
-                        out, m = group_aggregate_linear(child, node.key,
-                                                        node.values, wm, mgr)
+                        out, m = group_aggregate_linear(
+                            child, keys, node.values, wm, mgr,
+                            measures=node.measures)
                     m.host_syncs += syncs
                     self._stamp_grant(m, grant)
             finally:
@@ -1193,8 +1214,11 @@ class Executor:
         if isinstance(node, Aggregate):
             child = self._exec(node.child, metrics, decisions, mgr)
             if isinstance(child, DeviceRelation):
-                return _device_aggregate(child, node.column, node.fn)
-            col = child[node.column]
+                return _device_aggregate(child, node.column, node.fn,
+                                         node.measure)
+            col = (child[node.column] if node.measure is None
+                   else np.broadcast_to(np.asarray(node.measure(child)),
+                                        (len(child),)))
             if node.fn == "sum":
                 return float(col.sum())
             if node.fn == "count":
@@ -1217,11 +1241,19 @@ class _DeviceScalar:
     fn: str
 
 
-def _device_aggregate(rel: DeviceRelation, column: str, fn: str) -> _DeviceScalar:
-    """Masked scalar reduction on device; the root fetches the 0-d result."""
+def _device_aggregate(rel: DeviceRelation, column: str, fn: str,
+                      measure=None) -> _DeviceScalar:
+    """Masked scalar reduction on device of a column or of a computed
+    ``measure`` over the relation's columns; the root fetches the 0-d
+    result."""
     import jax.numpy as jnp
 
-    col = rel.col(column)
+    if measure is None:
+        col = rel.col(column)
+    else:
+        with jax.named_scope("measure"):
+            col = jnp.broadcast_to(jnp.asarray(measure(rel)),
+                                   (rel.num_physical_rows,))
     valid = rel.valid
     is_int = jnp.issubdtype(col.dtype, jnp.integer)
     n_valid = (jnp.asarray(col.shape[0], jnp.int64) if valid is None

@@ -141,10 +141,22 @@ class FusedSpec:
     sort_keys: Tuple[str, ...]     # () = no sort stage
     agg: Optional[Tuple[str, str]]  # (column, fn) for a scalar root, or None
     project: Optional[Tuple[str, ...]] = None  # relation-root column subset
+    # a computed measure (Expr over the joined columns) the scalar root
+    # reduces in place of a stored column; ``agg[0]`` is then its name
+    measure: Optional[object] = None
 
     def cache_signature(self) -> Tuple:
         return (self.join_key, _predicate_key(self.filter_fn),
-                self.sort_keys, self.agg, self.project)
+                self.sort_keys, self.agg, self.project,
+                None if self.measure is None else self.measure.cache_token())
+
+    def agg_column(self, view):
+        """The column the scalar root reduces: stored, or the measure
+        evaluated over the view."""
+        if self.measure is None:
+            return view[self.agg[0]]
+        with jax.named_scope("measure"):
+            return jnp.asarray(self.measure(view))
 
 
 def match_fragment(plan):
@@ -160,6 +172,7 @@ def match_fragment(plan):
 
     node = plan
     agg = None
+    measure = None
     sort_keys: Tuple[str, ...] = ()
     filter_fn = None
     project = None
@@ -170,6 +183,7 @@ def match_fragment(plan):
         if project is not None:
             return None  # Project(Aggregate) is not a planner shape
         agg = (node.column, node.fn)
+        measure = node.measure
         node = node.child
     if isinstance(node, Sort):
         sort_keys = tuple(node.keys)
@@ -186,8 +200,8 @@ def match_fragment(plan):
     build, probe = node.build.relation, node.probe.relation
     if len(build) == 0 or len(probe) == 0:
         return None  # degenerate inputs keep the generic path's exact semantics
-    return (FusedSpec(node.key, filter_fn, sort_keys, agg, project),
-            build, probe)
+    return (FusedSpec(node.key, filter_fn, sort_keys, agg, project,
+                      measure), build, probe)
 
 
 # ---------------------------------------------------------------------------
@@ -650,9 +664,10 @@ def _build_program(spec: FusedSpec, key: str, capacity: int,
                 perm = sorted_ops[-1]
 
         if spec.agg is not None:
-            col_name, fn = spec.agg
+            fn = spec.agg[1]
             with jax.named_scope("aggregate"):
-                col = view[col_name]
+                col = spec.agg_column(view)
+                col = jnp.broadcast_to(col, valid.shape)
                 v = valid if perm is None else jnp.take(valid, perm)
                 c = col if perm is None else jnp.take(col, perm)
                 # integer columns reduce in int64 (exact, matches the host
@@ -726,12 +741,24 @@ def sharded_supported(spec: FusedSpec, build: Relation,
     col, fn = spec.agg
     if fn == "count":
         return True
-    # the _JoinView naming contract: build wins b_<x> collisions
-    if col.startswith("b_") and col[2:] in build.names and col[2:] != key:
-        dtype = build[col[2:]].dtype
-    elif col in probe.names:
-        dtype = probe[col].dtype
+
+    def dtype_of(name):
+        # the _JoinView naming contract: build wins b_<x> collisions
+        if (name.startswith("b_") and name[2:] in build.names
+                and name[2:] != key):
+            return build[name[2:]].dtype
+        return probe[name].dtype if name in probe.names else None
+
+    if spec.measure is None:
+        dtype = dtype_of(col)
     else:
+        # the measure's dtype, from one row of ones of each column it reads
+        dts = {c: dtype_of(c) for c in spec.measure.columns()}
+        if any(dt is None for dt in dts.values()):
+            return False
+        dtype = np.asarray(spec.measure(
+            {c: np.ones(1, dt) for c, dt in dts.items()})).dtype
+    if dtype is None:
         return False
     if fn in ("min", "max"):
         return True
@@ -761,7 +788,7 @@ def _build_sharded_program(spec: FusedSpec, key: str, num_parts: int,
     from ..distributed.sharding import PART_AXIS, relational_mesh
 
     mesh = relational_mesh(num_parts)
-    col_name, fn = spec.agg
+    fn = spec.agg[1]
 
     def shard_body(bcols, pcols, bdicts, pdicts, brefs, prefs,
                    n_build, n_probe):
@@ -787,7 +814,7 @@ def _build_sharded_program(spec: FusedSpec, key: str, num_parts: int,
             part = valid.sum().astype(jnp.int64)
             scalar = jax.lax.psum(part, PART_AXIS)
         else:
-            c = view[col_name]
+            c = jnp.broadcast_to(spec.agg_column(view), valid.shape)
             is_int = jnp.issubdtype(c.dtype, jnp.integer)
             if fn == "sum":
                 zero = jnp.asarray(0, c.dtype)

@@ -26,7 +26,7 @@ dict-merge does (the build column wins).
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, Sequence, Tuple, Union
+from typing import Callable, Dict, Optional, Sequence, Tuple, Union
 
 from .relation import Relation
 
@@ -83,18 +83,42 @@ class LSort:
 
 @dataclasses.dataclass
 class LAggregate:
-    """Scalar reduction root (sum | count | min | max)."""
+    """Scalar reduction root (sum | count | min | max) over a stored column,
+    or over a computed measure: ``measure`` is then an
+    :class:`~repro.core.expr.Expr` over the child's columns and ``column``
+    its name."""
 
     child: "LogicalNode"
     column: str
     fn: str = "sum"
+    measure: Optional[object] = None
+
+    def reads(self) -> frozenset:
+        """Child columns the reduction reads."""
+        if self.measure is None:
+            return frozenset((self.column,))
+        return frozenset(self.measure.columns())
 
 
 @dataclasses.dataclass
 class LGroupBy:
+    """GROUP BY one or more key columns.  ``values`` maps a stored column or
+    a measure name to its aggregate function; ``measures`` names computed
+    measures (``Expr`` over the child's columns).  The output has one
+    column per key, then ``<fn>_<name>`` per value."""
+
     child: "LogicalNode"
-    key: str
-    values: Dict[str, str]  # column -> agg fn
+    keys: Tuple[str, ...]
+    values: Dict[str, str]  # column or measure name -> agg fn
+    measures: Dict[str, object] = dataclasses.field(default_factory=dict)
+
+    def reads(self) -> frozenset:
+        """Child columns the keys, the values and the measures read."""
+        out = set(self.keys)
+        for name in self.values:
+            m = self.measures.get(name)
+            out |= {name} if m is None else set(m.columns())
+        return frozenset(out)
 
 
 LogicalNode = Union[LScan, LFilter, LProject, LJoin, LSort, LAggregate,
@@ -129,7 +153,8 @@ def schema(node: LogicalNode) -> Tuple[str, ...]:
     if isinstance(node, LAggregate):
         return ()
     if isinstance(node, LGroupBy):
-        return (node.key,) + tuple(f"{fn}_{c}" for c, fn in node.values.items())
+        return tuple(node.keys) + tuple(f"{fn}_{c}"
+                                        for c, fn in node.values.items())
     raise TypeError(f"not a logical node: {node!r}")
 
 
@@ -159,8 +184,9 @@ def from_physical(plan) -> LogicalNode:
     if isinstance(plan, Sort):
         return LSort(from_physical(plan.child), tuple(plan.keys))
     if isinstance(plan, Aggregate):
-        return LAggregate(from_physical(plan.child), plan.column, plan.fn)
+        return LAggregate(from_physical(plan.child), plan.column, plan.fn,
+                          plan.measure)
     if isinstance(plan, GroupBy):
-        return LGroupBy(from_physical(plan.child), plan.key,
-                        dict(plan.values))
+        return LGroupBy(from_physical(plan.child), plan.keys,
+                        dict(plan.values), dict(plan.measures))
     raise TypeError(f"cannot lower {type(plan).__name__} to the logical IR")

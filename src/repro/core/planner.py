@@ -169,14 +169,9 @@ def prune_columns(node: LogicalNode,
     if isinstance(node, LSort):
         child_needed = None if needed is None else needed | set(node.keys)
         return LSort(prune_columns(node.child, child_needed), node.keys)
-    if isinstance(node, LAggregate):
-        return LAggregate(prune_columns(node.child,
-                                        frozenset((node.column,))),
-                          node.column, node.fn)
-    if isinstance(node, LGroupBy):
-        child_needed = frozenset((node.key,)) | set(node.values)
-        return LGroupBy(prune_columns(node.child, child_needed), node.key,
-                        node.values)
+    if isinstance(node, (LAggregate, LGroupBy)):
+        return dataclasses.replace(
+            node, child=prune_columns(node.child, node.reads()))
     if isinstance(node, LJoin):
         if needed is None:
             return LJoin(prune_columns(node.build),
@@ -378,9 +373,11 @@ class Stage:
             elif kind == "project":
                 node = Project(node, list(op[1]))
             elif kind == "group_by":
-                node = GroupBy(node, op[1], dict(op[2]))
+                keys = op[1]
+                node = GroupBy(node, keys[0] if len(keys) == 1 else keys,
+                               dict(op[2]), measures=dict(op[3]))
             elif kind == "agg":
-                node = Aggregate(node, op[1], op[2])
+                node = Aggregate(node, op[1], op[2], measure=op[3])
             else:
                 raise ValueError(kind)
         return node
@@ -404,10 +401,19 @@ class Stage:
             elif op[0] == "project":
                 parts.append(f"project{list(op[1])}")
             elif op[0] == "group_by":
-                parts.append(f"group_by[{op[1]}]{dict(op[2])}")
+                keys = op[1][0] if len(op[1]) == 1 else ",".join(op[1])
+                parts.append(f"group_by[{keys}]{_values_repr(op[2], op[3])}")
             elif op[0] == "agg":
-                parts.append(f"agg[{op[2]}({op[1]})]")
+                what = op[1] if op[3] is None else f"{op[1]}={op[3]!r}"
+                parts.append(f"agg[{op[2]}({what})]")
         return " → ".join(parts)
+
+
+def _values_repr(values, measures) -> str:
+    named = dict(measures)
+    return "{" + ", ".join(
+        f"{c!r}: {fn!r}" if c not in named else f"{c!r}={named[c]!r}: {fn!r}"
+        for c, fn in values) + "}"
 
 
 def _src_name(src) -> str:
@@ -501,9 +507,10 @@ def _compile_stage(node, stages) -> int:
         elif isinstance(w, LProject):
             ops.append(("project", tuple(w.columns)))
         elif isinstance(w, LGroupBy):
-            ops.append(("group_by", w.key, tuple(w.values.items())))
+            ops.append(("group_by", tuple(w.keys), tuple(w.values.items()),
+                        tuple(w.measures.items())))
         elif isinstance(w, LAggregate):
-            ops.append(("agg", w.column, w.fn))
+            ops.append(("agg", w.column, w.fn, w.measure))
     stages.append(Stage(join, input_src, tuple(ops)))
     return len(stages) - 1
 

@@ -35,7 +35,7 @@ keeps ``a``'s column names and serves ``b``'s non-key columns as
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence, Union
+from typing import Dict, Mapping, Optional, Sequence, Union
 
 from . import tracing
 from .executor import Executor, QueryResult
@@ -222,20 +222,62 @@ class Query:
                            f"{sorted(missing)}; have {self.schema()}")
         return self._derive(LSort(self._node, tuple(keys)))
 
-    def group_by(self, key: str, values: Dict[str, str]) -> "Query":
-        cols = {key} | set(values)
-        missing = cols - set(schema(self._node))
+    def _measure(self, spec, measures: Dict[str, Expr]) -> str:
+        """Resolve a value spec: a stored column's name, or a named
+        measure ``(name, Expr)``, recorded in ``measures``.  Returns the
+        name."""
+        have = set(schema(self._node))
+        if isinstance(spec, str):
+            if spec not in have:
+                raise KeyError(f"unknown column {spec!r}; have "
+                               f"{self.schema()}")
+            return spec
+        try:
+            name, expr = spec
+        except (TypeError, ValueError):
+            raise TypeError(f"a value is a column name or a named measure "
+                            f"(name, Expr), got {spec!r}") from None
+        if not (isinstance(name, str) and isinstance(expr, Expr)):
+            raise TypeError(f"a named measure is (str, Expr), got {spec!r}")
+        if name in have:
+            raise ValueError(f"measure name {name!r} is already a column")
+        missing = expr.columns() - have
+        if missing:
+            raise KeyError(f"measure {name!r} references unknown column(s) "
+                           f"{sorted(missing)}; have {self.schema()}")
+        if name in measures and measures[name] is not expr:
+            raise ValueError(f"two measures are named {name!r}")
+        measures[name] = expr
+        return name
+
+    def group_by(self, keys: Union[str, Sequence[str]], values) -> "Query":
+        """GROUP BY one key column or a tuple of them.  ``values`` maps each
+        value to its aggregate (sum | count | min | max), as a dict or as
+        ``(value, fn)`` pairs; a value is a stored column or a named
+        measure ``(name, Expr)``, e.g. ``{("profit", col("rev") -
+        col("cost")): "sum"}``.  The result has one column per key, then
+        ``<fn>_<name>`` per value."""
+        keys = (keys,) if isinstance(keys, str) else tuple(keys)
+        if not keys:
+            raise ValueError("group_by needs at least one key column")
+        missing = set(keys) - set(schema(self._node))
         if missing:
             raise KeyError(f"group_by references unknown column(s) "
                            f"{sorted(missing)}; have {self.schema()}")
-        return self._derive(LGroupBy(self._node, key, dict(values)))
+        pairs = values.items() if isinstance(values, Mapping) else values
+        measures: Dict[str, Expr] = {}
+        out: Dict[str, str] = {}
+        for spec, fn in pairs:
+            out[self._measure(spec, measures)] = fn
+        return self._derive(LGroupBy(self._node, keys, out, measures))
 
-    def aggregate(self, column: str, fn: str = "sum") -> "Query":
-        """Scalar reduction root: sum | count | min | max."""
-        if column not in schema(self._node):
-            raise KeyError(f"aggregate column {column!r} not in "
-                           f"{self.schema()}")
-        return self._derive(LAggregate(self._node, column, fn))
+    def aggregate(self, column, fn: str = "sum") -> "Query":
+        """Scalar reduction root: sum | count | min | max of a stored column
+        or of a named measure ``(name, Expr)``."""
+        measures: Dict[str, Expr] = {}
+        name = self._measure(column, measures)
+        return self._derive(LAggregate(self._node, name, fn,
+                                       measures.get(name)))
 
     # -- execution ---------------------------------------------------------
     def collect(self, rewrite: bool = True) -> QueryResult:

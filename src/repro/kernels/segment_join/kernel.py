@@ -16,8 +16,10 @@ identically on TPU hardware and in interpret mode (the CPU fallback):
   * :func:`join_table_build_pallas` / :func:`join_table_probe_pallas` —
     the hash-join core in the packed int32 code domain.  The table
     (per-slot count + build-row id) is tiled over the code domain; both
-    kernels run a 2-D grid (row tiles × domain blocks) and *skip* blocks
-    a tile cannot touch via ``pl.when`` on the tile's code min/max.
+    kernels run a 2-D grid over row tiles and domain blocks, ordered so
+    that each output block is revisited on consecutive steps only, and
+    *skip* blocks a tile cannot touch via ``pl.when`` on the tile's code
+    min/max.
     Radix-ordering the inputs first (via :func:`radix_rank_pallas`)
     clusters each tile's codes into one or two domain blocks, so the
     quadratic grid degenerates to a near-linear sweep — that is the
@@ -164,8 +166,8 @@ def radix_rank_pallas(bucket_ids, num_buckets: int, *, tblk: int = 1024,
 # ---------------------------------------------------------------------------
 
 def _table_build_kernel(bk_ref, brow_ref, cnt_ref, inv_ref, *, tblk, dblk):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    j = pl.program_id(0)    # domain block: the output block of this step
+    i = pl.program_id(1)    # build row tile
 
     @pl.when(i == 0)
     def _init():
@@ -175,7 +177,7 @@ def _table_build_kernel(bk_ref, brow_ref, cnt_ref, inv_ref, *, tblk, dblk):
     codes = bk_ref[...]                                    # [tblk] i32
     lo = j * dblk
     # radix-ordered inputs cluster each tile into one or two domain
-    # blocks; every other (tile, block) cell skips the one-hot entirely
+    # blocks; every other (block, tile) cell skips the one-hot entirely
     @pl.when((jnp.max(codes) >= lo) & (jnp.min(codes) < lo + dblk))
     def _accum():
         local = codes - lo
@@ -192,21 +194,28 @@ def join_table_build_pallas(bk, brow, domain_pad: int, *, tblk: int = 1024,
     """Build the tiled hash table: ``(cnt, inv)`` over ``[domain_pad]``
     slots, where ``cnt[c]`` counts build rows with code ``c`` and
     ``inv[c]`` holds the largest matching ``brow + 1`` (0 = empty slot).
-    Codes ≥ ``domain_pad`` are ignored (padding contract)."""
+    Codes ≥ ``domain_pad`` are ignored (padding contract).
+
+    The grid runs domain blocks outer and row tiles inner, so each output
+    block is accumulated on consecutive steps only.  The compiled kernel
+    writes an output block back to HBM when the next step's block differs
+    and never reads it back: with row tiles outer, a block revisited after
+    another block's steps started again from a stale buffer, and every
+    build row accumulated before was lost."""
     n = bk.shape[0]
     tblk = min(tblk, n)
     assert n % tblk == 0 and domain_pad % dblk == 0, (n, tblk, domain_pad)
     kernel = functools.partial(_table_build_kernel, tblk=tblk, dblk=dblk)
     return pl.pallas_call(
         kernel,
-        grid=(n // tblk, domain_pad // dblk),
+        grid=(domain_pad // dblk, n // tblk),
         in_specs=[
-            pl.BlockSpec((tblk,), lambda i, j: (i,)),
-            pl.BlockSpec((tblk,), lambda i, j: (i,)),
+            pl.BlockSpec((tblk,), lambda j, i: (i,)),
+            pl.BlockSpec((tblk,), lambda j, i: (i,)),
         ],
         out_specs=[
-            pl.BlockSpec((dblk,), lambda i, j: (j,)),
-            pl.BlockSpec((dblk,), lambda i, j: (j,)),
+            pl.BlockSpec((dblk,), lambda j, i: (j,)),
+            pl.BlockSpec((dblk,), lambda j, i: (j,)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((domain_pad,), jnp.int32),

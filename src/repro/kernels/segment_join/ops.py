@@ -126,30 +126,35 @@ def radix_hash_probe(bk, pk, domain: int, tblk: int = 1024, dblk: int = 1024,
     # are non-negative so arithmetic >> equals a logical shift, and the
     # jnp operator keeps int32 under jax_enable_x64 (lax.shift_* would
     # reject the weakly-typed int64 shift operand)
-    bdest, _ = radix_partition(bk >> shift, nblocks, tblk=tblk,
-                               interpret=interpret)
-    bk_ord, brow = _order(bk, bdest, nb)
-    pdest, _ = radix_partition(pk >> shift, nblocks, tblk=tblk,
-                               interpret=interpret)
-    pk_ord, _ = _order(pk, pdest, np_)
+    with jax.named_scope("pallas.partition.build"):
+        bdest, _ = radix_partition(bk >> shift, nblocks, tblk=tblk,
+                                   interpret=interpret)
+        bk_ord, brow = _order(bk, bdest, nb)
+    with jax.named_scope("pallas.partition.probe"):
+        pdest, _ = radix_partition(pk >> shift, nblocks, tblk=tblk,
+                                   interpret=interpret)
+        pk_ord, _ = _order(pk, pdest, np_)
     # 2. build the domain-tiled table (pad rows use code dpad: no block)
-    bpad = (-nb) % tblk
-    if bpad:
-        bk_ord = jnp.concatenate([bk_ord,
-                                  jnp.full((bpad,), dpad, jnp.int32)])
-        brow = jnp.concatenate([brow, jnp.zeros((bpad,), jnp.int32)])
-    cnt_t, inv_t = join_table_build_pallas(bk_ord, brow, dpad,
-                                           tblk=tblk, dblk=dblk,
-                                           interpret=interpret)
+    with jax.named_scope("pallas.table.build"):
+        bpad = (-nb) % tblk
+        if bpad:
+            bk_ord = jnp.concatenate([bk_ord,
+                                      jnp.full((bpad,), dpad, jnp.int32)])
+            brow = jnp.concatenate([brow, jnp.zeros((bpad,), jnp.int32)])
+        cnt_t, inv_t = join_table_build_pallas(bk_ord, brow, dpad,
+                                               tblk=tblk, dblk=dblk,
+                                               interpret=interpret)
     # 3. probe in radix order, then gather back to original row order
-    ppad = (-np_) % tblk
-    if ppad:
-        pk_ord = jnp.concatenate([pk_ord,
-                                  jnp.full((ppad,), dpad, jnp.int32)])
-    cnt_po, inv_po = join_table_probe_pallas(pk_ord, cnt_t, inv_t,
-                                             tblk=tblk, dblk=dblk,
-                                             interpret=interpret)
-    cnt_p = jnp.take(cnt_po, pdest)
-    build_row = jnp.take(inv_po, pdest) - 1
-    has_dup = jnp.max(cnt_t[:domain]) > 1
+    with jax.named_scope("pallas.table.probe"):
+        ppad = (-np_) % tblk
+        if ppad:
+            pk_ord = jnp.concatenate([pk_ord,
+                                      jnp.full((ppad,), dpad, jnp.int32)])
+        cnt_po, inv_po = join_table_probe_pallas(pk_ord, cnt_t, inv_t,
+                                                 tblk=tblk, dblk=dblk,
+                                                 interpret=interpret)
+    with jax.named_scope("pallas.gather"):
+        cnt_p = jnp.take(cnt_po, pdest)
+        build_row = jnp.take(inv_po, pdest) - 1
+        has_dup = jnp.max(cnt_t[:domain]) > 1
     return cnt_p, build_row, has_dup

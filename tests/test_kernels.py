@@ -10,6 +10,7 @@ from repro.kernels.moe_dispatch.ops import combine, dispatch, moe_dispatch_palla
 from repro.kernels.moe_dispatch.ref import combine_ref, dispatch_ref
 from repro.kernels.multikey_sort.ops import multikey_sort_lsd, tile_sort
 from repro.kernels.multikey_sort.ref import tile_sort_ref
+from repro.kernels.segment_join import kernel as segment_join_kernel
 from repro.kernels.segment_join.ops import (join_aggregate_kernel,
                                             radix_hash_probe, radix_partition,
                                             segment_sum)
@@ -199,6 +200,107 @@ def test_radix_hash_probe_all_dead_and_max_width():
     np.testing.assert_array_equal(np.asarray(row), np.asarray(row_r))
     # dead-slot pile-ups are NOT live duplicates (has_dup scans [0, domain))
     assert bool(has_dup) == bool(has_dup_r) == False  # noqa: E712
+
+
+def _supplier_join_case(n_probe, live_probe, seed):
+    """The SSB supplier join's codes: 2,000 live build codes in a 2,048
+    domain, padded with dead build rows to a 2,048-row bucket; probes draw
+    a live code each, and the padding probes sit in the dead slot."""
+    domain, live_build = 2048, 2000
+    bk = np.full(2048, domain, np.int32)
+    bk[:live_build] = np.arange(live_build)
+    pk = np.full(n_probe, domain, np.int32)
+    pk[:live_probe] = np.random.default_rng(seed).integers(0, live_build,
+                                                           live_probe)
+    # the build row of every code: live codes are their own row, the dead
+    # slot holds the largest dead row
+    row_of = np.append(np.arange(live_build), 2047)
+    want = np.where(pk == domain, 2047, row_of[np.minimum(pk, live_build)])
+    return bk, pk, domain, want
+
+
+def test_radix_hash_probe_build_rows_where_every_probe_matches():
+    """Per-row build-row ids, not counts: every live probe matches exactly
+    one build row, so a probe that read the wrong table slot still counts
+    once.  Three domain blocks (2,048 codes and the dead slot), dead build
+    rows in the dead slot, and a probe side over 48 row tiles."""
+    bk, pk, domain, want = _supplier_join_case(48 * 1024, 45_000, 15)
+    cnt, row, has_dup = radix_hash_probe(jnp.asarray(bk), jnp.asarray(pk),
+                                         domain, interpret=True)
+    np.testing.assert_array_equal(np.asarray(row), want)
+    np.testing.assert_array_equal(np.asarray(cnt)[:45_000], 1)
+    assert not bool(has_dup)
+
+
+def test_radix_hash_probe_build_rows_on_the_chip():
+    """The same at the SSB SF1 cell's shapes, compiled for the chip:
+    6,001,215 live probes in a 2^23 bucket."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("compiled Mosaic kernels need a TPU")
+    bk, pk, domain, want = _supplier_join_case(1 << 23, 6_001_215, 15)
+    cnt, row, has_dup = radix_hash_probe(jnp.asarray(bk), jnp.asarray(pk),
+                                         domain)
+    np.testing.assert_array_equal(np.asarray(row), want)
+    np.testing.assert_array_equal(np.asarray(cnt)[:6_001_215], 1)
+    assert not bool(has_dup)
+
+
+def _output_block_walks(fn, *args):
+    """For each Pallas call that ``fn`` makes, each output's block index
+    at every grid step, in the order the TPU runs the grid (row-major)."""
+    import itertools
+
+    walks = []
+
+    def visit(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                gm = eqn.params["grid_mapping"]
+                outs = gm.block_mappings[gm.num_inputs:]
+                steps = list(itertools.product(*map(range, gm.grid)))
+                for bm in outs:
+                    walks.append([tuple(int(x) for x in jax.core.eval_jaxpr(
+                        bm.index_map_jaxpr.jaxpr, bm.index_map_jaxpr.consts,
+                        *map(np.int32, step))) for step in steps])
+                continue
+            for v in eqn.params.values():      # jitted inner calls
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        visit(sub)
+
+    visit(jax.make_jaxpr(fn)(*args).jaxpr)
+    return walks
+
+
+@pytest.mark.parametrize("name,fn,shapes", [
+    ("segment_sum", lambda s, v: segment_join_kernel.segment_sum_pallas(
+        s, v, 256, tblk=1024, interpret=True),
+     [((4096,), jnp.int32), ((4096,), jnp.float32)]),
+    ("radix_rank", lambda b: segment_join_kernel.radix_rank_pallas(
+        b, 3, interpret=True), [((4096,), jnp.int32)]),
+    ("table_build", lambda b, r: segment_join_kernel.join_table_build_pallas(
+        b, r, 3072, interpret=True),
+     [((2048,), jnp.int32), ((2048,), jnp.int32)]),
+    ("table_probe", lambda p, c, i: segment_join_kernel.join_table_probe_pallas(
+        p, c, i, interpret=True),
+     [((4096,), jnp.int32), ((3072,), jnp.int32), ((3072,), jnp.int32)]),
+])
+def test_segment_join_output_blocks_are_revisited_on_consecutive_steps(
+        name, fn, shapes):
+    """The compiled kernel writes an output block back when the next grid
+    step moves to another block and never reads it back, so an
+    accumulating block must not be left and revisited later (the fault
+    that lost the hash table's first domain block on the chip)."""
+    args = [jnp.zeros(s, dt) for s, dt in shapes]
+    walks = _output_block_walks(fn, *args)
+    assert walks, name
+    for walk in walks:
+        left = set()
+        for prev, cur in zip(walk, walk[1:]):
+            if cur != prev:
+                left.add(prev)
+                assert cur not in left, (name, walk)
 
 
 def test_join_aggregate_kernel_matches_core():

@@ -77,6 +77,19 @@ def test_radix_hash_probe_compiles(one_chip, domain):
              ((domain,), jnp.int32), ((ROWS,), jnp.int32))
 
 
+@pytest.mark.parametrize("rows,domain_pad", [
+    (2048, 3072),    # SSB's supplier: 2,048 codes and the dead slot
+    (8192, 5120),    # the 4096 gate: several row tiles per domain block
+])
+def test_join_table_build_compiles(one_chip, rows, domain_pad):
+    """The table build, domain blocks outer and row tiles inner, so each
+    output block accumulates on consecutive grid steps."""
+    from repro.kernels.segment_join import kernel
+
+    _compile(lambda b, r: kernel.join_table_build_pallas(b, r, domain_pad),
+             one_chip, ((rows,), jnp.int32), ((rows,), jnp.int32))
+
+
 @pytest.mark.parametrize("max_abs,takes_kernel", [
     (50, False),   # SSB-like measure: 2^23 * 50 > 2^24, jnp core
     (1, True),     # 0/1 flags: 2^23 * 1 < 2^24, the f32 kernel is exact

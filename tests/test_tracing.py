@@ -14,7 +14,8 @@ import numpy as np
 import pytest
 
 from repro.core import (Aggregate, Executor, Join, QueryResult, QueryServer,
-                        QueryTrace, Relation, Scan, Session, Sort, tracing)
+                        QueryTrace, Relation, Scan, Session, Sort, col,
+                        tracing)
 from repro.core import aggregate as aggregate_mod
 from repro.core import fused
 from repro.core import tensor_engine as te
@@ -210,16 +211,34 @@ def test_fused_program_carries_scope_names(core, scopes):
         assert "join.dense" not in text
 
 
+def test_pallas_probe_carries_its_stage_scopes():
+    """Inside ``join.dense.pallas`` the radix probe names its stages: the
+    radix partition of each side, the table build, the table probe and
+    the gather back to row order."""
+    text = _lowered(dense_domain=32, use_kernel=True)
+    for scope in ("pallas.partition.build", "pallas.partition.probe",
+                  "pallas.table.build", "pallas.table.probe",
+                  "pallas.gather"):
+        assert f'"{scope}/' in text, scope
+    assert "jit(program)/join.dense.pallas/jit(radix_hash_probe)" in text
+
+
 def test_per_operator_programs_carry_scope_names():
     keys = jnp.arange(16, dtype=jnp.int64)
     assert "op.join/" in te._join_plan.lower(keys, keys).as_text(
         debug_info=True)
     assert "op.sort/" in te._multikey_perm.lower(
         (keys,), None, num_keys=1).as_text(debug_info=True)
-    reduce = aggregate_mod._group_reduce_jit()
-    text = reduce.lower(keys, None, (keys,), ("sum",), 16,
-                        (False,)).as_text(debug_info=True)
+    group = aggregate_mod._group_program_jit()
+    text = group.lower((keys,), None, {"k": keys}, (("k", "sum", None),),
+                       16, (False,)).as_text(debug_info=True)
     assert "op.group_by/" in text
+    # several keys and a computed measure
+    measure = aggregate_mod.Measure("m", col("k") * 2)
+    text = group.lower((keys, keys), None, {"k": keys},
+                       (("m", "sum", measure),), 16,
+                       (False,)).as_text(debug_info=True)
+    assert "op.group_by/measure/" in text
 
 
 def _instructions(text):
