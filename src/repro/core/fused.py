@@ -232,6 +232,13 @@ class _JoinView:
     columns as ``b_<name>``, probe's key column under the join key).  Filter
     predicates receive this view — numpy-style expressions trace through it.
 
+    ``probe_idx`` None means the identity: output row ``i`` is probe row
+    ``i`` (a probe-order join core), and probe columns are served as they
+    are stored, with no gather.  ``build_idx`` may be a pair ``(perm,
+    idx)``: a build column is then permuted by ``perm`` at the build
+    bucket and gathered by ``idx``, which is ``take(col, perm[idx])``
+    without composing the two indices at the output's length.
+
     Packed columns are stored as narrow codes: the gather moves code-width
     bytes and the decode to logical values runs *after* it, so the expensive
     data movement inside the program happens at packed width and consumers
@@ -243,7 +250,8 @@ class _JoinView:
         self._bcols = bcols
         self._pcols = pcols
         self._key = key
-        self._bidx = build_idx
+        self._bidx = (build_idx if isinstance(build_idx, tuple)
+                      else (build_idx,))
         self._pidx = probe_idx
         self._bdec = bdec or {}
         self._pdec = pdec or {}
@@ -264,10 +272,14 @@ class _JoinView:
             with jax.named_scope("gather"):
                 if (name.startswith("b_") and name[2:] in self._bcols
                         and name[2:] != self._key):
-                    col = jnp.take(self._bcols[name[2:]], self._bidx)
+                    col = self._bcols[name[2:]]
+                    for idx in self._bidx:
+                        col = jnp.take(col, idx)
                     dec = self._bdec.get(name[2:])
                 elif name in self._pcols:
-                    col = jnp.take(self._pcols[name], self._pidx)
+                    col = self._pcols[name]
+                    if self._pidx is not None:
+                        col = jnp.take(col, self._pidx)
                     dec = self._pdec.get(name)
                 else:
                     raise KeyError(name)
@@ -422,9 +434,20 @@ def _align_sorted(sk, pk):
     return left[B:], right[B:]
 
 
-def _join_sorted(bk, pk, n_build, n_probe, capacity):
+def _join_sorted(bk, pk, n_build, n_probe, capacity,
+                 probe_order: bool = False):
     """General join core: sorted coordinate alignment (a device sort of
-    the build side, then :func:`_align_sorted`)."""
+    the build side, then :func:`_align_sorted`).
+
+    ``probe_order`` (static) is the form for build keys believed unique:
+    each probe row matches at most one build row, so output row ``i`` is
+    probe row ``i`` (``probe_idx`` None) at the probe bucket, with no
+    expansion.  Its ``build_idx`` is the pair ``(order, left)``, so build
+    columns are sorted at the build bucket and gathered by ``left``, and
+    the int64 ``order`` is never gathered at the probe bucket.
+    ``has_dup`` checks the belief and ``total`` is the join's true size,
+    so :func:`run_fused`'s retry on the expanding form takes the right
+    capacity at once."""
     B = bk.shape[0]
     P = pk.shape[0]
     iota_b = jnp.arange(B)
@@ -441,6 +464,12 @@ def _join_sorted(bk, pk, n_build, n_probe, capacity):
         # the int64 sentinel would false-match padded build rows, so it is
         # excluded (documented key-domain contract)
         counts = jnp.where((iota_p < n_probe) & (pk != _I64_MAX), counts, 0)
+    if probe_order:
+        with jax.named_scope("join.expand"):
+            # a probe's one match is the sorted build row at its left bound
+            build_idx = (order, jnp.clip(left, 0, B - 1))
+            return (build_idx, None, counts > 0, counts.sum(),
+                    counts.max() > 1)
     with jax.named_scope("join.prefix_sum"):
         ends = _prefix(counts, jax.lax.add)
         starts = ends - counts
@@ -498,7 +527,7 @@ def _join_sorted_run(sk, pk, n_probe, capacity):
 
 
 def _join_dense(bk, pk, n_build, n_probe, capacity, domain: int, kmin,
-                use_kernel: bool = False):
+                use_kernel: bool = False, probe_order: bool = False):
     """Dense-domain join core: the key IS a coordinate axis.
 
     When the build key domain is dense enough to materialize as an axis of
@@ -517,6 +546,10 @@ def _join_dense(bk, pk, n_build, n_probe, capacity, domain: int, kmin,
     contract those kernels tile over, and the dead slot ``domain`` is
     their padding slot.  Results are bit-for-bit the jnp scatter path's
     (kernel parity is regression-tested in tests/test_kernels.py).
+
+    ``probe_order`` (static) returns the rows in probe order at the probe
+    bucket (``probe_idx`` None), as :func:`_join_sorted` does; the
+    expanding form compacts the matched probe rows to the front.
     """
     B = bk.shape[0]
     P = pk.shape[0]
@@ -536,25 +569,19 @@ def _join_dense(bk, pk, n_build, n_probe, capacity, domain: int, kmin,
                 bk0c.astype(jnp.int32), pk0c.astype(jnp.int32), domain,
                 True)
             matched = p_live & (cnt_p > 0)
-        with jax.named_scope("join.prefix_sum"):
-            ends = _prefix(matched.astype(jnp.int64), jax.lax.add)
-            total = ends[-1]
-        with jax.named_scope("join.expand"):
-            slot = jnp.arange(capacity, dtype=jnp.int64)
-            pos = jnp.where(matched, jnp.minimum(ends - 1, capacity - 1),
-                            capacity)
-            probe_idx = jnp.zeros((capacity + 1,),
-                                  jnp.int64).at[pos].max(iota_p)[:capacity]
-            build_idx = jnp.take(jnp.maximum(brow, 0).astype(jnp.int64),
-                                 probe_idx)
-            valid = slot < total
-        return build_idx, probe_idx, valid, total, has_dup
-    with jax.named_scope("join.dense.build"):
-        cnt = jnp.zeros((domain + 1,), jnp.int32).at[bk0c].add(1)
-        has_dup = cnt[:domain].max() > 1
-        inv = jnp.zeros((domain + 1,), jnp.int64).at[bk0c].set(iota_b)
-    with jax.named_scope("join.dense.probe"):
-        matched = p_live & (cnt[pk0c] > 0)
+    else:
+        with jax.named_scope("join.dense.build"):
+            cnt = jnp.zeros((domain + 1,), jnp.int32).at[bk0c].add(1)
+            has_dup = cnt[:domain].max() > 1
+            inv = jnp.zeros((domain + 1,), jnp.int64).at[bk0c].set(iota_b)
+        with jax.named_scope("join.dense.probe"):
+            matched = p_live & (cnt[pk0c] > 0)
+    with jax.named_scope("join.expand"):
+        # each probe row's build row (read only where it matched)
+        hit = (jnp.maximum(brow, 0) if use_kernel
+               else jnp.take(inv, pk0c))
+        if probe_order:
+            return hit, None, matched, matched.sum(), has_dup
     with jax.named_scope("join.prefix_sum"):
         ends = _prefix(matched.astype(jnp.int64), jax.lax.add)
         total = ends[-1]
@@ -564,7 +591,7 @@ def _join_dense(bk, pk, n_build, n_probe, capacity, domain: int, kmin,
                         capacity)
         probe_idx = jnp.zeros((capacity + 1,),
                               jnp.int64).at[pos].max(iota_p)[:capacity]
-        build_idx = jnp.take(inv, jnp.take(pk0c, probe_idx))
+        build_idx = jnp.take(hit, probe_idx)
         valid = slot < total
     return build_idx, probe_idx, valid, total, has_dup
 
@@ -572,12 +599,15 @@ def _join_dense(bk, pk, n_build, n_probe, capacity, domain: int, kmin,
 def _build_program(spec: FusedSpec, key: str, capacity: int,
                    dense_domain: Optional[int] = None,
                    key_mode: str = "value", use_kernel: bool = False,
-                   bsig: Tuple = (), psig: Tuple = ()):
+                   bsig: Tuple = (), psig: Tuple = (),
+                   probe_order: bool = False):
     """Trace-time closure for one (fragment, capacity, bucket) cache entry.
 
     ``dense_domain`` (a static power-of-two bucket) selects the sort-free
     coordinate join core; the domain offset ``kmin`` stays a traced scalar so
-    drifting key ranges reuse the compiled program.
+    drifting key ranges reuse the compiled program.  ``probe_order`` selects
+    the join cores' form for unique build keys: rows stay in probe order
+    at the probe bucket (``capacity`` must be it) and nothing expands.
 
     ``bsig``/``psig`` are the static per-column layout signatures
     (:meth:`~repro.core.codec_device.DeviceColumnLayout.signature`) of the
@@ -630,10 +660,10 @@ def _build_program(spec: FusedSpec, key: str, capacity: int,
         if dense_domain is not None:
             build_idx, probe_idx, valid, total, has_dup = _join_dense(
                 bk, pk, n_build, n_probe, capacity, dense_domain, kmin,
-                use_kernel=use_kernel)
+                use_kernel=use_kernel, probe_order=probe_order)
         else:
             build_idx, probe_idx, valid, total, has_dup = _join_sorted(
-                bk, pk, n_build, n_probe, capacity)
+                bk, pk, n_build, n_probe, capacity, probe_order)
 
         view = _JoinView(bcols, pcols, key, build_idx, probe_idx, bdec, pdec)
         if spec.filter_fn is not None:
@@ -867,17 +897,22 @@ def _build_sharded_program(spec: FusedSpec, key: str, num_parts: int,
 def _host_plan(build: Relation, probe: Relation, key: str):
     """Host-side planning from the numpy inputs — free of device traffic.
 
-    Returns ``(capacity, dense_domain, kmin)``: an optimistic capacity bucket
-    from the cached key-cardinality sketch (:func:`repro.core.table_cache.
-    key_stats` — repeated queries do not re-sample), and — when the build key
-    domain is dense enough to materialize as a coordinate axis and the sample
-    predicts unique keys — the power-of-two domain bucket for the sort-free
-    dense join core.  Both predictions are *verified on device* (overflow /
-    has_dup piggyback on the result fetch), so a wrong guess costs one retry,
-    never a wrong answer.
+    Returns ``(capacity, dense_domain, kmin, probe_order)``: an optimistic
+    capacity bucket from the cached key-cardinality sketch
+    (:func:`repro.core.table_cache.key_stats` — repeated queries do not
+    re-sample); when the build key domain is dense enough to materialize as
+    a coordinate axis and the sample predicts unique keys, the power-of-two
+    domain bucket for the sort-free dense join core; and whether the sample
+    predicts unique keys, so the join core can keep its rows in probe order
+    at the probe bucket.  The predictions are *verified on device*
+    (overflow / has_dup piggyback on the result fetch), so a wrong guess
+    costs one retry, never a wrong answer.
     """
     stats = key_stats(build, key)
     capacity = capacity_bucket(int(len(probe) * stats.dup))
+    # the probe-order cores' output is the probe bucket itself
+    probe_order = (stats.dup == 1.0
+                   and capacity == capacity_bucket(len(probe)))
     dense_domain = None
     kmin = 0
     if stats.dup == 1.0 and stats.n:
@@ -885,7 +920,7 @@ def _host_plan(build: Relation, probe: Relation, key: str):
         width = int(stats.kmax) - kmin + 1
         if width <= 4 * capacity_bucket(stats.n):
             dense_domain = capacity_bucket(width)
-    return capacity, dense_domain, kmin
+    return capacity, dense_domain, kmin, probe_order
 
 
 def run_fused(spec: FusedSpec, build: Relation, probe: Relation,
@@ -896,7 +931,10 @@ def run_fused(spec: FusedSpec, build: Relation, probe: Relation,
 
     Happy path: one compiled program launch + one batched device→host fetch.
     Capacity overflow (optimistic bucket too small) re-runs once at the exact
-    bucket; both programs stay cached for subsequent queries.
+    bucket; both programs stay cached for subsequent queries.  A build key
+    the sample finds unique runs the probe-order join cores; a repeat the
+    sample missed is caught on device (``has_dup``) and re-runs once on the
+    expanding core, at the join's true size.
 
     Device dispatch acquires a :class:`~repro.core.resource_broker.
     DeviceLease` from ``broker`` (the process-wide default broker when none
@@ -940,8 +978,8 @@ def run_fused(spec: FusedSpec, build: Relation, probe: Relation,
         # host planning is part of the query's wall time (the per-op
         # baseline pays for its planning inside its timers too)
         with tracing.span("rel.host_prep"):
-            capacity, dense_domain, kmin = _host_plan(build, probe,
-                                                      spec.join_key)
+            capacity, dense_domain, kmin, probe_order = _host_plan(
+                build, probe, spec.join_key)
             layouts_b, up_b, log_b = get_device_layouts(build, b_bucket)
             layouts_p, up_p, log_p = get_device_layouts(probe, p_bucket)
             bcols = {k: dc.codes for k, dc in layouts_b.items()}
@@ -978,19 +1016,21 @@ def run_fused(spec: FusedSpec, build: Relation, probe: Relation,
                               if dense_domain is not None else False)
                 cache_key = (spec.cache_signature(), capacity, b_bucket,
                              p_bucket, dense_domain, key_mode, use_kernel,
-                             bsig, psig)
+                             bsig, psig, probe_order)
                 prog, fresh = _CACHE.get(
                     cache_key,
                     lambda: _build_program(spec, spec.join_key, capacity,
                                            dense_domain, key_mode,
-                                           use_kernel, bsig, psig))
+                                           use_kernel, bsig, psig,
+                                           probe_order))
             # a FRESH program's first call pays multi-second XLA
             # compilation; running it outside the queue keeps one novel
             # shape from stalling every other query's device phase (its
             # own unserialized execution is a one-off, and compiling runs
             # never feed the runtime profile anyway)
             any_fresh = any_fresh or fresh
-            tracing.count(fresh_programs=int(fresh), dispatches=1)
+            tracing.count(fresh_programs=int(fresh), dispatches=1,
+                          probe_order_joins=int(probe_order))
             lease = None
             if not fresh:
                 lease = broker.device_lease(batch_key=("fused", cache_key))
@@ -1014,23 +1054,29 @@ def run_fused(spec: FusedSpec, build: Relation, probe: Relation,
                 _CACHE.mark_ready(cache_key)
             syncs += 1
             total = int(fetched["total"])
-            if dense_domain is not None and bool(fetched["has_dup"]):
+            retry = False
+            if ((dense_domain is not None or probe_order)
+                    and bool(fetched["has_dup"])):
                 # optimistic unique-key guess was wrong: fall back to the
-                # sorted core over decoded int64 values (code-domain joins
-                # included — the sorted core's sentinel contract is int64)
+                # expanding sorted core over decoded int64 values (code-
+                # domain joins included — the sorted core's sentinel
+                # contract is int64)
                 dense_domain = None
                 key_mode = "value"
                 kmin = 0
-                tracing.count(retries=1)
-                continue
-            if total <= capacity:
+                probe_order = False
+                retry = True
+            if total > capacity:
+                if guard is not None:
+                    # the overflow IS the observed fan-out: let the
+                    # execution-time guard re-check the fragment decision
+                    # before paying the retry dispatch (raises SwitchPoint
+                    # to abandon)
+                    guard.observe_fragment(total, capacity)
+                capacity = capacity_bucket(total)  # rare: bucket overflowed
+                retry = True
+            if not retry:
                 break
-            if guard is not None:
-                # the overflow IS the observed fan-out: let the execution-
-                # time guard re-check the fragment decision before paying
-                # the retry dispatch (raises SwitchPoint to abandon)
-                guard.observe_fragment(total, capacity)
-            capacity = capacity_bucket(total)  # rare: bucket overflowed
             tracing.count(retries=1)
         with tracing.span("rel.assemble"):
             if spec.agg is not None:

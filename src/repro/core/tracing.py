@@ -62,12 +62,16 @@ class QueryTrace:
     ran a program for the first time (a compile, or a load from the
     persistent cache); ``retries`` the fused re-runs after a capacity
     overflow or a duplicate-key guess; ``dispatches`` every device program
-    dispatch, fused or per operator."""
+    dispatch, fused or per operator; ``probe_order_joins`` the fused
+    dispatches whose join core kept its rows in probe order because the
+    build keys were believed unique (read with ``retries``: a wrong belief
+    costs one retry on the expanding core)."""
 
     seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
     fresh_programs: int = 0
     retries: int = 0
     dispatches: int = 0
+    probe_order_joins: int = 0
 
     def ms(self, *names: str) -> float:
         """Milliseconds summed over the spans ``names``."""
@@ -97,13 +101,14 @@ def query() -> Iterator[QueryTrace]:
 
 
 def count(fresh_programs: int = 0, retries: int = 0,
-          dispatches: int = 0) -> None:
+          dispatches: int = 0, probe_order_joins: int = 0) -> None:
     """Add to the calling thread's query counters (no-op outside a query)."""
     trace = current()
     if trace is not None:
         trace.fresh_programs += fresh_programs
         trace.retries += retries
         trace.dispatches += dispatches
+        trace.probe_order_joins += probe_order_joins
 
 
 class span:

@@ -10,6 +10,7 @@ segments or code domains — the gate in ``tensor_engine.use_pallas``.
 The topology is described inside a fixture: only the worker that runs this
 file loads the TPU library, and a host that cannot describe it skips.
 """
+import functools
 import os
 
 import jax
@@ -112,11 +113,16 @@ def test_segment_sum_rule_exact_at_scale(monkeypatch, max_abs, takes_kernel):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
-def _sorted_program_text(one_chip, build_rows, probe_rows):
+@functools.lru_cache(maxsize=None)
+def _sorted_program_text(one_chip, build_rows, probe_rows,
+                         probe_order=False):
+    """The compiled sorted-core program's text; cached, because a compile
+    at the SF1 buckets takes minutes on a CPU host."""
     from repro.core import fused
 
     spec = fused.FusedSpec("k", None, (), ("b_v", "sum"))
-    prog = fused._build_program(spec, "k", probe_rows)
+    prog = fused._build_program(spec, "k", probe_rows,
+                                probe_order=probe_order)
 
     def shape(n):
         return jax.ShapeDtypeStruct((n,), jnp.int64, sharding=one_chip)
@@ -146,10 +152,24 @@ def test_sorted_join_aligns_by_sort_where_probes_are_many(
         one_chip, build_rows, probe_rows, merged):
     """At the SF1 lineitem ⋈ partsupp buckets the sorted core aligns by
     one merged sort: ``join.sorted.search`` holds sorts and no ``while``
-    (the binary search's loop); a small probe side keeps the search."""
-    text = _sorted_program_text(one_chip, build_rows, probe_rows)
+    (the binary search's loop); a small probe side keeps the search.  The
+    program is the probe-order form, which unique build keys run."""
+    text = _sorted_program_text(one_chip, build_rows, probe_rows, True)
     scope = 'op_name="jit(program)/join.sorted.search/'
     ops = [ln for ln in text.splitlines() if scope in ln]
     assert ops
     assert any(" sort(" in ln for ln in ops) is merged
     assert any(" while(" in ln for ln in ops) is not merged
+
+
+def test_probe_order_core_expands_nothing_on_the_tpu(one_chip):
+    """At the SF1 lineitem ⋈ partsupp buckets (2^20 build, 2^23 probe) the
+    probe-order sorted core compiles with no scatter under ``join.expand``
+    and no array of ``capacity + 1`` slots: the expanding core's int64
+    seed slots, which the chip carries as pairs of u32."""
+    text = _sorted_program_text(one_chip, 1 << 20, ROWS, True)
+    expand = [ln for ln in text.splitlines()
+              if 'op_name="jit(program)/join.expand/' in ln]
+    assert expand
+    assert not any(" scatter(" in ln for ln in expand)
+    assert f"[{ROWS + 1}]" not in text

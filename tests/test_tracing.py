@@ -117,6 +117,30 @@ def test_fresh_programs_counted_first_run_then_warm():
     assert second.trace.ms("rel.plan", "rel.select") > 0
 
 
+def _dup_tables(seed: int, prefix: str):
+    """The TPC-H tables with every partsupp row listed twice."""
+    tables, key = _tpch_tables(seed, prefix=prefix)
+    ps = tables["partsupp"]
+    tables["partsupp"] = Relation({n: np.concatenate([ps[n], ps[n]])
+                                   for n in ps.names})
+    return tables, key
+
+
+@pytest.mark.parametrize("tables,joins", [(_tpch_tables, 1),
+                                          (_dup_tables, 0)])
+def test_probe_order_joins_count_unique_builds(tables, joins):
+    """A build key the sample finds unique (partsupp's packed primary key)
+    runs the probe-order core once; a duplicated build runs the expanding
+    core and counts nothing."""
+    tables, key = tables(11, prefix=f"po{joins}_")
+    sess = Session(work_mem=4 << 20, policy="tensor")
+    for name, rel in tables.items():
+        sess.register(name, rel)
+    res = _q9(sess, key).collect()
+    assert (res.trace.probe_order_joins, res.trace.dispatches,
+            res.trace.retries) == (joins, 1, 0)
+
+
 def test_capacity_overflow_counts_one_retry():
     """The key sample sees unique keys, the tail repeats one key: the
     optimistic capacity overflows and the fragment re-runs once.  The keys
@@ -184,15 +208,17 @@ def test_result_built_outside_a_query_has_an_empty_trace():
     assert res.trace.ms("rel.query") == 0.0
 
 
-def _lowered(dense_domain=None, use_kernel=False):
+def _lowered(dense_domain=None, use_kernel=False, probe_order=False,
+             dialect=None):
     spec = fused.FusedSpec("k", None, (), ("b_v", "sum"))
     prog = fused._build_program(spec, "k", 64, dense_domain=dense_domain,
-                                use_kernel=use_kernel)
+                                use_kernel=use_kernel,
+                                probe_order=probe_order)
     keys = jnp.arange(32, dtype=jnp.int64)
     bcols = {"k": keys, "v": keys}
     pcols = {"k": jnp.concatenate([keys, keys]), "w": jnp.zeros(64, int)}
     return prog.lower(bcols, pcols, {}, {}, {}, {}, 32, 64,
-                      0).as_text(debug_info=True)
+                      0).as_text(dialect=dialect, debug_info=True)
 
 
 @pytest.mark.parametrize("core,scopes", [
@@ -209,6 +235,22 @@ def test_fused_program_carries_scope_names(core, scopes):
         assert f"jit(program)/{scope}/" in text, scope
     if core == "sorted":
         assert "join.dense" not in text
+
+
+@pytest.mark.parametrize("core", ["sorted", "dense", "pallas"])
+@pytest.mark.parametrize("probe_order", [False, True])
+def test_probe_order_core_scatters_nothing_in_its_expansion(core,
+                                                             probe_order):
+    """The probe-order form of each join core keeps its one lookup per
+    probe row under ``join.expand`` and scatters nothing there; the
+    expanding form scatters each matched probe row to its first slot."""
+    text = _lowered(dense_domain=None if core == "sorted" else 32,
+                    use_kernel=core == "pallas", probe_order=probe_order,
+                    dialect="hlo")
+    expand = [ln for ln in text.splitlines()
+              if 'op_name="jit(program)/join.expand/' in ln]
+    assert expand
+    assert any(" scatter(" in ln for ln in expand) is not probe_order
 
 
 def test_pallas_probe_carries_its_stage_scopes():
